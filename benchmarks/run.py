@@ -76,6 +76,8 @@ def main(argv=None) -> None:
                     help="comma-separated suite names to run (default: all)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_agg, bench_analysis, bench_api,
                             bench_dist, bench_expr, bench_kernels,
                             bench_linalg, bench_ml, bench_oo,

@@ -151,8 +151,10 @@ def worker_config_violation(num_workers: int, expr_backend: str,
                 "run expr_backend='jax': XLA's runtime threads do not "
                 "survive the fork that spawns the connecting workers — "
                 "use socket_launch='thread' (in-process workers over "
-                "real TCP) or socket_launch='connect' (external worker "
-                "processes with their own jax)")
+                "real TCP, which share this process's devices) or "
+                "socket_launch='connect' (external worker processes, each "
+                "on a host of its own: a chip belongs to one process, so "
+                "processes on one host cannot share its chip)")
     if launch == "connect" and (socket_addr is None or socket_addr[1] == 0):
         return ("socket_launch='connect' needs an explicit "
                 "socket_addr=(host, port) with a nonzero port — "

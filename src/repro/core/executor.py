@@ -189,6 +189,10 @@ class Executor:
             for j in range(0, len(page_records), self.vector_rows):
                 batch = page_records[j: j + self.vector_rows]
                 parts[dest[i]].append(VectorList({col: batch}))
+        if not any(parts):
+            # an empty set still flows one zero-row batch through the
+            # stages, so the output columns carry their computed dtypes
+            parts[0].append(VectorList({col: np.zeros(0, s.dtype)}))
         return parts
 
     def _map_batches(self, parts, fn) -> List[List[VectorList]]:

@@ -13,7 +13,7 @@ into a single compiled callable per batch, with two backends:
 * ``jax`` — the same run split into a host prologue (structured-field
   access, registered methods, byte-string compares, key hashing) and one
   ``jax.jit``-ed core for the numeric cmp/bool/arith DAG, executed under
-  ``enable_x64`` so int64/float64 semantics match numpy bit-for-bit.
+  ``jax.enable_x64`` so int64/float64 semantics match numpy bit-for-bit.
   Batches are padded to power-of-two buckets so XLA retraces O(log n)
   times, not once per tail length.
 
@@ -492,9 +492,8 @@ def _compile_jax(ir: _RunIR, arrays: Tuple) -> Callable:
     epilogue, scheduled statically from zero-row dtype propagation."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    status, _dtypes = schedule_jax_run(ir, arrays)
+    status, dtypes = schedule_jax_run(ir, arrays)
 
     pre = [i for i in ir.instrs if status[i.out] == "pre"]
     core = [i for i in ir.instrs if status[i.out] == "jit"]
@@ -537,7 +536,7 @@ def _compile_jax(ir: _RunIR, arrays: Tuple) -> Callable:
             if core_jit is not None:
                 n_pad = _bucket(n0)
                 xs = [_pad_to(np.asarray(env[s]), n_pad) for s in ext]
-                with enable_x64():
+                with jax.enable_x64(True):
                     outs = core_jit(*xs)
                 for s, o in zip(ret, outs):
                     env[s] = np.asarray(o)[:n0]
@@ -550,4 +549,8 @@ def _compile_jax(ir: _RunIR, arrays: Tuple) -> Callable:
             return tuple(np.asarray(env[s])[m] for s in ir.out_slots)
         return tuple(env[s] for s in ir.out_slots)
 
+    # the jitted core (None when the run has no device work) and the dtypes
+    # of its arguments, so it can be lowered from shapes alone
+    kernel.core = core_jit
+    kernel.core_dtypes = tuple(dtypes[s] for s in ext)
     return kernel

@@ -574,9 +574,10 @@ def device_segment_reducer(combiners: Tuple[str, ...],
     Bit-identity with the host scatters is test-pinned where XLA lowers
     the scatter to a sequential row-order accumulation (CPU, via the
     forced tests below). Float scatter-add ordering on other accelerator
-    backends is XLA-implementation-defined: when enabling this path on
-    real devices, run the forced equivalence tests there first — min/max
-    and integer/count sums are order-free and always safe.
+    backends is XLA-implementation-defined, and a TPU emulates f64: on a
+    TPU v5e, TPC-H Q1's float sums differ from the host's in the last
+    bits (about 1e-11 relative, ``chip_smoke.py``). Min/max and
+    integer/count sums are order-free and stay exact.
 
     Like the physical planner's broadcast decision, the offload must win
     on modeled cost: XLA's *CPU* scatter is ~50x slower per element than
@@ -589,23 +590,18 @@ def device_segment_reducer(combiners: Tuple[str, ...],
     The returned reducer itself returns ``None`` per call for non-numeric
     value dtypes (caller falls back to the numpy scatter)."""
     import os
-    try:
-        import jax  # noqa: F401
-    except Exception:  # pragma: no cover - jax is a hard dep in-tree
+
+    import jax
+    import jax.numpy as jnp
+
+    if (not (force or os.environ.get("REPRO_AGG_DEVICE") == "1")
+            and jax.default_backend() == "cpu"):
         return None
-    if not (force or os.environ.get("REPRO_AGG_DEVICE") == "1"):
-        try:
-            if jax.default_backend() == "cpu":
-                return None
-        except Exception:  # pragma: no cover - backend probe failed
-            return None
 
     def reducer(inv: np.ndarray, n: int, vals: List[np.ndarray]):
         if any(v.dtype.kind not in "biuf" or v.dtype.names is not None
                for v in vals):
             return None
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
         acc_dtypes = [sum_acc_dtype(v.dtype) if c == "sum"
                       else np.dtype(np.float64)
                       for c, v in zip(combiners, vals)]
@@ -616,8 +612,6 @@ def device_segment_reducer(combiners: Tuple[str, ...],
         with _SEG_LOCK:
             kern = _SEG_KERNELS.get(key)
         if kern is None:
-            import jax
-
             def _core(inv_d, *vals_d):
                 outs = []
                 for comb, v, dt in zip(combiners, vals_d, acc_dtypes):
@@ -646,7 +640,7 @@ def device_segment_reducer(combiners: Tuple[str, ...],
             vp = np.zeros((rows,) + v.shape[1:], v.dtype)
             vp[:len(v)] = v
             vals_p.append(vp)
-        with enable_x64():
+        with jax.enable_x64(True):
             outs = kern(inv_p, *vals_p)
         return [np.asarray(o)[:n] for o in outs]
 

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size
-
 __all__ = ["segment_preaggregate", "two_stage_aggregate",
            "grad_reduce_two_stage", "broadcast_join", "hash_partition_join"]
 
@@ -47,7 +45,7 @@ def grad_reduce_two_stage(grads: Any, axis_name: str) -> Any:
     """Reduce-scatter each gradient leaf over its first divisible dim; the
     caller updates its shard and all-gathers (see train_step shard_map
     variant). Falls back to psum for tiny/indivisible leaves."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def red(g):
         if g.ndim >= 1 and g.shape[0] % n == 0 and g.shape[0] >= n:
@@ -87,7 +85,7 @@ def hash_partition_join(keys: jax.Array, values: jax.Array,
 
     keys: (T,), values: (T, d). Returns the shard's received (keys, values)
     with -1 key marking empty slots."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     T = keys.shape[0]
     cap = T // n * 2  # per-destination capacity
     dest = (keys % num_partitions) * n // num_partitions

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 __all__ = ["pipeline_forward", "pipeline_loss"]
 
 
@@ -71,7 +69,7 @@ def pipeline_forward(stage_fn: Callable[[Any, jax.Array], jax.Array],
             axis)
         return outs.reshape(-1, *x_local.shape[1:])
 
-    fn = shard_map(spmd, mesh=mesh,
+    fn = jax.shard_map(spmd, mesh=mesh,
                        in_specs=(P(axis), P()),
                        out_specs=P(),
                        check_vma=False)

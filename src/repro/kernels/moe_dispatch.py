@@ -16,7 +16,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["moe_gather"]
 
@@ -26,7 +25,7 @@ def _kernel(x_ref, ids_ref, keep_ref, o_ref, *, block_slots: int):
 
     def body(i, _):
         tid = ids_ref[base + i]
-        row = pl.load(x_ref, (jnp.maximum(tid, 0), slice(None)))
+        row = x_ref[jnp.maximum(tid, 0), :]
         keep = keep_ref[base + i]
         o_ref[i, :] = jnp.where(keep > 0, row, jnp.zeros_like(row))
         return 0
@@ -54,9 +53,9 @@ def moe_gather(x: jax.Array, token_ids: jax.Array, keep: jax.Array,
         kern,
         grid=(Sp // block_slots,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # token matrix in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # handles
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),  # token matrix in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # handles
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((block_slots, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Sp, d), x.dtype),

@@ -18,7 +18,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention"]
 
@@ -37,10 +36,8 @@ def _kernel(q_ref, kp_ref, vp_ref, tbl_ref, len_ref, o_ref, *,
         m, l, acc = carry
         page_id = tbl_ref[b, p]  # Handle dereference (int32 page id)
         pid = jnp.maximum(page_id, 0)
-        k = pl.load(kp_ref, (pid, slice(None), kh, slice(None))
-                    ).astype(jnp.float32)  # (page, hd)
-        v = pl.load(vp_ref, (pid, slice(None), kh, slice(None))
-                    ).astype(jnp.float32)
+        k = kp_ref[pid, :, kh, :].astype(jnp.float32)  # (page, hd)
+        v = vp_ref[pid, :, kh, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -81,10 +78,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid=(B, K),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), lambda b, k: (b, k, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # page pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # block tables
-            pl.BlockSpec(memory_space=pltpu.ANY),  # lengths
+            pl.BlockSpec(memory_space=pl.ANY),  # page pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),  # block tables
+            pl.BlockSpec(memory_space=pl.ANY),  # lengths
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, k: (b, k, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),

@@ -239,8 +239,6 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str,
         return rec
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older JAX returns [dict]
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_rec = {k: int(getattr(mem, k)) for k in

@@ -20,7 +20,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs import ArchConfig
 from repro.models.context import Ctx
 from repro.models.layers import ffn_apply, ffn_defs
@@ -207,7 +206,7 @@ def _moe_apply_ep_shard_map(cfg: ArchConfig, p: Dict, x: jax.Array, ctx: Ctx
         return y_full.reshape(xin.shape), aux
 
     experts_p = {kk: p[kk] for kk in expert_specs}
-    fn = shard_map(
+    fn = jax.shard_map(
         local_moe, mesh=ctx.mesh,
         in_specs=(P(None, None), expert_specs, P(b_ax, None, None)),
         out_specs=(P(b_ax, None, None), P()),

@@ -138,8 +138,11 @@ class QueryService:
             raise ValueError(
                 "QueryService(launch='fork') cannot run "
                 "expr_backend='jax': XLA's runtime threads do not survive "
-                "the fork that spawns the pool — use launch='thread' or "
-                "external workers via launch='connect'")
+                "the fork that spawns the pool — use launch='thread' "
+                "(workers share this process's devices) or external "
+                "workers via launch='connect', each on a host of its own: "
+                "a chip belongs to one process, so processes on one host "
+                "cannot share its chip")
         self.store = store if store is not None else PagedStore()
         self.P = num_workers
         self.launch = launch

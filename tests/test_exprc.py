@@ -90,6 +90,19 @@ def test_division_and_empty_batches_equivalent():
     assert len(next(iter(r.values()))) == 0
 
 
+@pytest.mark.parametrize("be", BACKENDS)
+def test_empty_set_output_keeps_computed_dtypes(be):
+    # a set with no rows still yields typed (not float64) empty columns,
+    # as the analyzer infers them: the hypothesis counterexample n=0
+    sess = Session(num_partitions=1, expr_backend=be)
+    ds = sess.load("t", _rows(0), TRow)
+    for q in (ds.select(lambda t: t.a),
+              ds.filter(lambda t: t.b > 0).select(lambda t: t.a * 2)):
+        r = q.collect()
+        assert {c: v.dtype for c, v in r.items()} == q.check().output_schema
+        assert all(v.dtype == np.int64 and len(v) == 0 for v in r.values())
+
+
 def test_agg_topk_and_native_barrier_equivalent():
     _collect_all(lambda s, ds: (
         ds.filter(lambda t: t.a > -90)

@@ -2,16 +2,14 @@
 single-device). Covers: small-mesh dry-run for every arch family, shard_map
 two-stage aggregation / joins, pipeline parallelism, elastic re-mesh.
 
-The former deterministic failures here (``jax.shard_map`` /
-``jax.lax.axis_size`` missing on this jax build) are fixed at the root via
-:mod:`repro.compat`. What remains environment-sensitive is the *subprocess
-multi-device init itself*: under some sandboxed runners, a child spawned
-with piped stdio intermittently hangs inside bare
+Each child runs on the CPU with virtual devices (``JAX_PLATFORMS=cpu`` and
+``--xla_force_host_platform_device_count``), so no child ever reaches for an
+accelerator the parent may hold. Meshes come from
+:func:`repro.launch.mesh.make_mesh` (Auto axes). Under some sandboxed
+runners a child spawned with piped stdio intermittently hangs inside bare
 ``jax.make_mesh``/XLA CPU client startup (no repro code on the stack, near
-zero CPU). A one-shot canary probes that up front and skips the module
-with a reason when the environment is in its broken state; a mid-run hang
-likewise skips rather than fails — so tier-1 ``pytest -x`` runs green end
-to end either way, and healthy environments run everything for real."""
+zero CPU); a one-shot canary probes that up front and skips the module
+with a reason when the environment is in that state."""
 import json
 import os
 import subprocess
@@ -37,7 +35,7 @@ def _probe_canary(timeout: int = 90) -> bool:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         r = subprocess.run(
             [sys.executable, "-c",
@@ -66,7 +64,7 @@ def _run(code: str, devices: int = 8, timeout: int = 600):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                            capture_output=True, text=True, timeout=timeout,
@@ -86,17 +84,18 @@ def _run(code: str, devices: int = 8, timeout: int = 600):
     return r.stdout
 
 
-def test_dryrun_small_mesh_every_family():
+def test_dryrun_small_mesh_every_family(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["REPRO_DRYRUN_DEVICES"] = "16"
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         r = subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun",
              "--arch",
              "gemma_7b,phi35_moe,xlstm_125m,jamba15_large,whisper_small",
              "--shape", "train_4k,decode_32k",
-             "--mesh", "single", "--out", "/tmp/dryrun_test"],
+             "--mesh", "single", "--out", str(tmp_path)],
             capture_output=True, text=True, timeout=1800, env=env, cwd=ROOT)
     except subprocess.TimeoutExpired:
         if not _probe_canary():
@@ -110,12 +109,12 @@ def test_two_stage_aggregate_shard_map():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from repro.launch.mesh import make_mesh
     from repro.engine.aggregation import two_stage_aggregate
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     keys = jnp.arange(64) % 16
     vals = jnp.arange(64, dtype=jnp.float32)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda k, v: two_stage_aggregate(k, v, 16, "data"),
         mesh=mesh, in_specs=(P("data"), P("data")), out_specs=P("data"))
     got = fn(keys, vals)
@@ -129,14 +128,14 @@ def test_broadcast_and_hash_joins_shard_map():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from repro.launch.mesh import make_mesh
     from repro.engine.aggregation import broadcast_join, hash_partition_join
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     probe = jnp.arange(32) % 10
     build_k = jnp.arange(10)
     build_v = (jnp.arange(10) * 10.0)[:, None]
     # broadcast join: build side sharded, gathered inside
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p, bk, bv: broadcast_join(p, bk, bv, "data"),
         mesh=mesh, in_specs=(P("data"), P("data"), P("data")),
         out_specs=P("data"))
@@ -144,7 +143,7 @@ def test_broadcast_and_hash_joins_shard_map():
     got = np.asarray(v)[np.asarray(m)]
     assert set(got.flatten().tolist()) <= set((build_v.flatten()).tolist())
     # hash-partition join: rows land on the shard owning their key bucket
-    fn2 = shard_map(
+    fn2 = jax.shard_map(
         lambda k, v: hash_partition_join(k, v, 4, "data"),
         mesh=mesh, in_specs=(P("data"), P("data")), out_specs=P("data"))
     keys = jnp.arange(64) % 4
@@ -162,7 +161,8 @@ def test_pipeline_parallel_matches_sequential():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.engine.pipeline_parallel import pipeline_forward
-    mesh = jax.make_mesh((4,), ("pipe",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("pipe",))
     S, B, D = 4, 8, 16
     rng = jax.random.PRNGKey(0)
     Ws = jax.random.normal(rng, (S, D, D)) / np.sqrt(D)
@@ -183,11 +183,12 @@ def test_elastic_restore_to_new_mesh(tmp_path):
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.checkpoint import Checkpointer
+    from repro.launch.mesh import make_mesh
     ck = Checkpointer({str(tmp_path)!r})
     state = {{"w": jnp.arange(64.0).reshape(8, 8)}}
     ck.save(1, state)
     # restore onto a 2x4 mesh with w sharded over both axes
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     got, _ = ck.restore(state, specs={{"w": P("data", "model")}}, mesh=mesh)
     np.testing.assert_allclose(np.asarray(got["w"]), np.asarray(state["w"]))
     assert len(got["w"].sharding.device_set) == 8
@@ -206,6 +207,7 @@ def test_gradients_identical_with_and_without_compression_off():
     from repro.models import build_model, Ctx
     from repro.engine import make_train_step, TrainConfig
     from repro.optim import init_opt_state, AdamWConfig
+    from repro.launch.mesh import make_mesh
     cfg = reduced_config(get_arch("phi3_mini"))
     model = build_model(cfg)
     params = model.init_params(jax.random.PRNGKey(0), "float32")
@@ -214,7 +216,7 @@ def test_gradients_identical_with_and_without_compression_off():
     batch = {"tokens": toks, "labels": toks}
     ts = jax.jit(make_train_step(model, Ctx(), TrainConfig()))
     p1, _, _, m1 = ts(params, opt, None, batch)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     with mesh:
         sb = jax.device_put(batch, NamedSharding(mesh, P("data", None)))
         ts2 = jax.jit(make_train_step(model, Ctx(), TrainConfig()))
@@ -234,11 +236,12 @@ def test_ep_shard_map_matches_gspmd_baseline():
     from repro.configs import get_arch, reduced_config, get_shape
     from repro.core.planner import make_plan
     from repro.models import build_model, Ctx
+    from repro.launch.mesh import make_mesh
     cfg = dataclasses.replace(reduced_config(get_arch("phi35_moe")),
                               capacity_factor=4.0)
     model = build_model(cfg)
     params = model.init_params(jax.random.PRNGKey(0), "float32")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = make_plan(cfg, {"data": 2, "model": 4}, get_shape("train_4k"))
     assert plan.moe_strategy == "ep"
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,

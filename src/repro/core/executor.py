@@ -281,24 +281,33 @@ class Executor:
         # partitioned on the key tuple, every partial holds only keys
         # routing to itself — the split+merge is the identity permutation,
         # so the partials *are* the finals and no bytes move
+        rec = current()
         if elide:
             self.stats.exchanges_elided += 1
             finals = partials
         else:
             sb0 = self.stats.shuffle_bytes
-            with current().span(f"x:shuffle:{i}:partials", cat="exchange",
-                                tag=f"{i}:partials") as sp:
-                finals = [AggMap(spec) for _ in range(self.P)]
+            with rec.span(f"x:shuffle:{i}:partials", cat="exchange",
+                          tag=f"{i}:partials") as sp:
+                splits = []
                 for m in partials:
-                    split = m.split_by_key_hash(self.P)
-                    for p in range(self.P):
-                        if split[p].data:
-                            self.stats.shuffle_bytes += split[p].nbytes()
-                            finals[p].merge(split[p])
+                    with rec.span("agg:split", cat="kernel"):
+                        splits.append(m.split_by_key_hash(self.P))
+                # each final merges its share of every partial, in
+                # partition order
+                finals = [AggMap(spec) for _ in range(self.P)]
+                for p, final in enumerate(finals):
+                    with rec.span("agg:merge", cat="kernel"):
+                        for split in splits:
+                            if split[p].data:
+                                self.stats.shuffle_bytes += \
+                                    split[p].nbytes()
+                                final.merge(split[p])
             sp.set(bytes=self.stats.shuffle_bytes - sb0)
         out: List[List[VectorList]] = [[] for _ in range(self.P)]
         for p, m in enumerate(finals):
-            emitted = m.emit()
+            with rec.span("agg:emit", cat="kernel"):
+                emitted = m.emit()
             if emitted is not None:
                 out[p].append(emitted)
         return out

@@ -44,7 +44,7 @@ from repro.core.lambdas import METHOD_REGISTRY, _APPLY_BINOP as _NP_BINOP
 from repro.core.relops import hash_col, reset_segment_kernels
 from repro.core.tcap import TCAPOp, TCAPProgram
 from repro.obs.metrics import METRICS
-from repro.obs.trace import current
+from repro.obs.trace import current, watch_compiles
 from repro.objectmodel.vectorlist import VectorList
 
 __all__ = ["FusedStage", "build_steps", "kernel_cache_info",
@@ -290,9 +290,10 @@ class FusedStage:
 
     def _specialize(self, dsig: Tuple, arrays: Tuple) -> Callable:
         # runs once per (stage, dtype signature) — the per-batch hot path
-        # memoizes in self._kern — so the metrics/tracing work here is off
-        # the per-row/per-batch cost model. METRICS calls sit outside
-        # _KLOCK (the registry has its own lock).
+        # memoizes in self._kern — so the metrics work here is off the
+        # per-row/per-batch cost model. METRICS calls sit outside _KLOCK
+        # (the registry has its own lock). XLA compiles later, at the
+        # first call of each bucket: obs.trace.watch_compiles spans it.
         key = None if self.sig is None else (self.backend, self.sig, dsig)
         if key is not None:
             with _KLOCK:
@@ -306,13 +307,10 @@ class FusedStage:
                 METRICS.inc("kernel_cache.hits")
                 return kern
             METRICS.inc("kernel_cache.misses")
-        with current().span("kernel:compile", cat="kernel",
-                            backend=self.backend,
-                            stage="+".join(op.op for op in self.ops)):
-            if self.backend == "jax":
-                kern = _compile_jax(self.ir, arrays)
-            else:
-                kern = _compile_numpy(self.ir)
+        if self.backend == "jax":
+            kern = _compile_jax(self.ir, arrays)
+        else:
+            kern = _compile_numpy(self.ir)
         if key is not None:
             evicted = 0
             with _KLOCK:
@@ -507,8 +505,9 @@ def _compile_jax(ir: _RunIR, arrays: Tuple) -> Callable:
         needed_after.update(ins.ins)
     ret = sorted({ins.out for ins in core} & needed_after)
 
+    watch_compiles()
     if core:
-        def _core(*xs):
+        def fused_stage(*xs):
             env: Dict[int, Any] = dict(zip(ext, xs))
             for ins in core:
                 if ins.kind == "bool":
@@ -523,30 +522,40 @@ def _compile_jax(ir: _RunIR, arrays: Tuple) -> Callable:
                     env[ins.out] = _NP_BINOP[ins.payload](a, b)
             return tuple(env[s] for s in ret)
 
-        core_jit = jax.jit(_core)
+        core_jit = jax.jit(fused_stage)
     else:
         core_jit = None
 
     def kernel(A: Tuple) -> Tuple:
+        # one span per phase per batch: host prologue, padding + upload +
+        # launch, device wait + download, host epilogue + compaction
+        rec = current()
         env: Dict[int, Any] = dict(enumerate(A))
         n0 = A[0].shape[0] if A else 0
         with np.errstate(all="ignore"):
-            for ins in pre:
-                env[ins.out] = _eval_host(ins, env, n0)
+            with rec.span("stage:pre", cat="kernel"):
+                for ins in pre:
+                    env[ins.out] = _eval_host(ins, env, n0)
             if core_jit is not None:
-                n_pad = _bucket(n0)
-                xs = [_pad_to(np.asarray(env[s]), n_pad) for s in ext]
-                with jax.enable_x64(True):
-                    outs = core_jit(*xs)
-                for s, o in zip(ret, outs):
-                    env[s] = np.asarray(o)[:n0]
-            for ins in post:
-                env[ins.out] = _eval_host(ins, env, n0)
-        if ir.masks:
-            m = np.asarray(env[ir.masks[0]], bool)
-            for s in ir.masks[1:]:
-                m = m & np.asarray(env[s], bool)
-            return tuple(np.asarray(env[s])[m] for s in ir.out_slots)
+                with rec.span("stage:put", cat="kernel"):
+                    n_pad = _bucket(n0)
+                    xs = [_pad_to(np.asarray(env[s]), n_pad) for s in ext]
+                    METRICS.inc("device.h2d.bytes.total",
+                                sum(x.nbytes for x in xs))
+                    with jax.enable_x64(True):
+                        outs = core_jit(*xs)
+                with rec.span("stage:wait", cat="kernel"):
+                    for s, o in zip(ret, outs):
+                        env[s] = np.asarray(o)[:n0]
+            with rec.span("stage:post", cat="kernel"):
+                for ins in post:
+                    env[ins.out] = _eval_host(ins, env, n0)
+                if ir.masks:
+                    m = np.asarray(env[ir.masks[0]], bool)
+                    for s in ir.masks[1:]:
+                        m = m & np.asarray(env[s], bool)
+                    return tuple(np.asarray(env[s])[m]
+                                 for s in ir.out_slots)
         return tuple(env[s] for s in ir.out_slots)
 
     # the jitted core (None when the run has no device work) and the dtypes

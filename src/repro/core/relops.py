@@ -34,6 +34,7 @@ Kernels:
 """
 from __future__ import annotations
 
+import contextlib
 import struct
 import threading
 from dataclasses import dataclass
@@ -43,6 +44,8 @@ import numpy as np
 
 from repro.core.lambdas import METHOD_REGISTRY
 from repro.core.tcap import TCAPOp
+from repro.obs.metrics import METRICS
+from repro.obs.trace import current, watch_compiles
 from repro.objectmodel.vectorlist import VectorList
 
 __all__ = [
@@ -435,6 +438,7 @@ class AggMap:
         # to int64 and narrow S(n) keys to the longest seen value,
         # contradicting the synthesized group schema)
         self.key_dtypes: Optional[List[np.dtype]] = None
+        self._keys_span = None
 
     def absorb(self, key_cols: Sequence[np.ndarray],
                val_cols: Sequence[np.ndarray],
@@ -445,25 +449,33 @@ class AggMap:
         it receives ``(inv, n_groups, val_arrays)`` and must return one
         ``(n_groups, ...)`` array per accumulator — or ``None`` to decline
         (non-numeric dtypes), falling back to numpy."""
-        if len(np.asarray(key_cols[0])) == 0:
-            return
-        if self.key_dtypes is None:
-            self.key_dtypes = [np.asarray(c).dtype for c in key_cols]
-        keys, inv = _unique_keys(key_cols)
+        rec = current()
+        # absorb_batches hands over its open agg:keys span, so that one
+        # span covers the concatenation and the grouping
+        keys_span, self._keys_span = self._keys_span, None
+        with keys_span or rec.span("agg:keys", cat="kernel"):
+            if len(np.asarray(key_cols[0])) == 0:
+                return
+            if self.key_dtypes is None:
+                self.key_dtypes = [np.asarray(c).dtype for c in key_cols]
+            keys, inv = _unique_keys(key_cols)
         n = len(keys)
         vals = [np.asarray(v) for v in val_cols]
+        # the device reducer records its own agg:put and agg:wait spans
         accs = reducer(inv, n, vals) if reducer is not None else None
         if accs is None:
-            accs = [_COMBINE[comb](None, inv, v, n)
-                    for comb, v in zip(self.spec.combiners, vals)]
+            with rec.span("agg:scatter", cat="kernel"):
+                accs = [_COMBINE[comb](None, inv, v, n)
+                        for comb, v in zip(self.spec.combiners, vals)]
         combs = self.spec.combiners
-        for i, k in enumerate(keys):
-            cur = self.data.get(k)
-            if cur is None:
-                self.data[k] = [a[i] for a in accs]
-            else:
-                self.data[k] = [_MERGE2[c](old, a[i])
-                                for c, old, a in zip(combs, cur, accs)]
+        with rec.span("agg:fold", cat="kernel"):
+            for i, k in enumerate(keys):
+                cur = self.data.get(k)
+                if cur is None:
+                    self.data[k] = [a[i] for a in accs]
+                else:
+                    self.data[k] = [_MERGE2[c](old, a[i])
+                                    for c, old, a in zip(combs, cur, accs)]
 
     def absorb_batches(self, batches: Sequence[VectorList],
                        key_cols: Sequence[str],
@@ -476,12 +488,14 @@ class AggMap:
         partition) is identical on every backend by construction."""
         if not batches:
             return
-        self.absorb(
-            [np.concatenate([np.asarray(vl[c]) for vl in batches])
-             for c in key_cols],
-            [np.concatenate([np.asarray(vl[c]) for vl in batches])
-             for c in acc_cols],
-            reducer=reducer)
+        with contextlib.ExitStack() as stack:
+            stack.enter_context(current().span("agg:keys", cat="kernel"))
+            keys = [np.concatenate([np.asarray(vl[c]) for vl in batches])
+                    for c in key_cols]
+            vals = [np.concatenate([np.asarray(vl[c]) for vl in batches])
+                    for c in acc_cols]
+            self._keys_span = stack.pop_all()
+        self.absorb(keys, vals, reducer=reducer)
 
     def merge(self, other: "AggMap") -> None:
         if self.key_dtypes is None:
@@ -597,6 +611,7 @@ def device_segment_reducer(combiners: Tuple[str, ...],
     if (not (force or os.environ.get("REPRO_AGG_DEVICE") == "1")
             and jax.default_backend() == "cpu"):
         return None
+    watch_compiles()
 
     def reducer(inv: np.ndarray, n: int, vals: List[np.ndarray]):
         if any(v.dtype.kind not in "biuf" or v.dtype.names is not None
@@ -612,7 +627,7 @@ def device_segment_reducer(combiners: Tuple[str, ...],
         with _SEG_LOCK:
             kern = _SEG_KERNELS.get(key)
         if kern is None:
-            def _core(inv_d, *vals_d):
+            def segment_reduce(inv_d, *vals_d):
                 outs = []
                 for comb, v, dt in zip(combiners, vals_d, acc_dtypes):
                     shape = (segs,) + v.shape[1:]
@@ -628,21 +643,26 @@ def device_segment_reducer(combiners: Tuple[str, ...],
                         outs.append(op(v.astype(dt), mode="drop"))
                 return tuple(outs)
 
-            kern = jax.jit(_core)
+            kern = jax.jit(segment_reduce)
             with _SEG_LOCK:
                 while len(_SEG_KERNELS) >= _SEG_KERNELS_CAP:
                     _SEG_KERNELS.pop(next(iter(_SEG_KERNELS)))
                 _SEG_KERNELS[key] = kern
-        inv_p = np.full(rows, segs, np.int64)
-        inv_p[:len(inv)] = inv
-        vals_p = []
-        for v in vals:
-            vp = np.zeros((rows,) + v.shape[1:], v.dtype)
-            vp[:len(v)] = v
-            vals_p.append(vp)
-        with jax.enable_x64(True):
-            outs = kern(inv_p, *vals_p)
-        return [np.asarray(o)[:n] for o in outs]
+        rec = current()
+        with rec.span("agg:put", cat="kernel"):
+            inv_p = np.full(rows, segs, np.int64)
+            inv_p[:len(inv)] = inv
+            vals_p = []
+            for v in vals:
+                vp = np.zeros((rows,) + v.shape[1:], v.dtype)
+                vp[:len(v)] = v
+                vals_p.append(vp)
+            METRICS.inc("device.h2d.bytes.total",
+                        inv_p.nbytes + sum(vp.nbytes for vp in vals_p))
+            with jax.enable_x64(True):
+                outs = kern(inv_p, *vals_p)
+        with rec.span("agg:wait", cat="kernel"):
+            return [np.asarray(o)[:n] for o in outs]
 
     return reducer
 

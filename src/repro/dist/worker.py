@@ -41,7 +41,7 @@ from repro.dist.protocol import (DRIVER, HELLO, PROTO_VERSION, SETUP,
                                  WELCOME, ProtocolError, StatsFrame,
                                  configure_socket, decode_agg_map,
                                  encode_agg_map, read_frame, write_frame)
-from repro.obs.trace import NULL, SpanRecorder, op_name, using
+from repro.obs.trace import NULL, SpanRecorder, current, op_name, using
 from repro.objectmodel.store import PagedSet, PagedStore
 from repro.objectmodel.vectorlist import VectorList
 
@@ -195,6 +195,7 @@ class WorkerRuntime:
         # local simulation — identical association order by construction)
         m = AggMap(spec)
         m.absorb_batches(batches, kcols, acols, reducer=reducer)
+        rec = current()
         if elide:
             # the planner proved this shard's rows are already stable_key_
             # hash-partitioned on the key tuple: every key in `m` routes
@@ -203,9 +204,11 @@ class WorkerRuntime:
             # branch together (agg_elide ships with the wire plan), so no
             # rank blocks in recv.
             self.stats.exchanges_elided += 1
-            emitted = m.emit()
+            with rec.span("agg:emit", cat="kernel"):
+                emitted = m.emit()
             return [emitted] if emitted is not None else []
-        split = m.split_by_key_hash(self.P)
+        with rec.span("agg:split", cat="kernel"):
+            split = m.split_by_key_hash(self.P)
         tag = f"{i}:partials"
         # packed multi-column partial maps ride the same page-block wire
         # as batches (accumulators cross the wire, never finalized means)
@@ -216,17 +219,21 @@ class WorkerRuntime:
             if block is not None:
                 self.stats.shuffle_bytes += block.nbytes
             self.tr.send(dst, tag, block)
-        final = AggMap(spec)
+        parts = []
         for src in range(self.P):
             if src == self.rank:
-                part = split[self.rank]
+                parts.append(split[self.rank])
             else:
                 block = self.tr.recv(src, tag)
-                part = (decode_agg_map(block, spec)
-                        if block is not None else None)
-            if part is not None and part.data:
-                final.merge(part)
-        emitted = final.emit()
+                if block is not None:
+                    parts.append(decode_agg_map(block, spec))
+        final = AggMap(spec)
+        with rec.span("agg:merge", cat="kernel"):
+            for part in parts:
+                if part.data:
+                    final.merge(part)
+        with rec.span("agg:emit", cat="kernel"):
+            emitted = final.emit()
         return [emitted] if emitted is not None else []
 
     def _topk(self, op: TCAPOp, i: int,

@@ -21,8 +21,9 @@ tracing on.
 from repro.obs.metrics import METRICS, MetricsRegistry
 from repro.obs.render import last_run_lines, render_analyze
 from repro.obs.trace import (NULL, NullRecorder, QueryTrace, Span,
-                             SpanRecorder, current, op_name, using)
+                             SpanRecorder, current, op_name, using,
+                             watch_compiles)
 
 __all__ = ["METRICS", "MetricsRegistry", "NULL", "NullRecorder",
            "QueryTrace", "Span", "SpanRecorder", "current", "op_name",
-           "using", "last_run_lines", "render_analyze"]
+           "using", "watch_compiles", "last_run_lines", "render_analyze"]

@@ -23,6 +23,11 @@ rows.scanned.total        ctr    Session from per-query ExecStats
 rows.output.total         ctr    Session from per-query ExecStats
 shuffle.bytes.total       ctr    Session from per-query ExecStats
 exchanges.elided.total    ctr    Session from per-query ExecStats
+device.h2d.bytes.total    ctr    jax backend at each upload, padded: the
+                                 fused-stage core's arguments and the
+                                 segment reducer's ``inv`` and value columns
+jax.compiles.total        ctr    obs.trace compile listener, per XLA compile
+jax.compile_ms.total      ctr    obs.trace compile listener (compile time)
 ========================  =====  =============================================
 
 The persistent query service (``repro.service``) adds:

@@ -22,21 +22,41 @@ kwargs. Sites additionally guard any non-trivial attribute computation
 (row counts) behind ``recorder.enabled``.
 
 Determinism contract: span *structure* (names, categories, parentage,
-per-plan counts) is a pure function of the physical plan and worker
-count — never of timing, memory addresses, or hash seeds — so tests can
-assert exact span trees while durations vary.
+per-plan counts) is a pure function of the physical plan, the worker
+count and the batch count of the input (the jax backend's fused stages
+record their ``stage:*`` phases once per batch) — never of timing,
+memory addresses, or hash seeds — so tests can assert exact span trees
+while durations vary. One exception: a ``compile`` span appears only
+where XLA compiled inside the query, which depends on what the process
+compiled before.
+
+Profiler clock: while a recorder is enabled and ``jax`` is imported,
+every span it opens also enters a ``jax.profiler.TraceAnnotation`` of
+the same name, so a running ``jax.profiler`` trace holds the query, op,
+stage and aggregation spans on the profiler's own clock, above the XLA
+ops they launched. :data:`NULL` never annotates.
+
+Compiles: :func:`watch_compiles` (called by the jax backend on first
+use) registers one process-wide ``jax.monitoring`` listener that counts
+every XLA compile in :data:`~repro.obs.metrics.METRICS`
+(``jax.compiles.total``, ``jax.compile_ms.total``) and, where the
+compiling thread's ambient recorder is enabled, records a finished
+``compile`` span (category ``kernel``) under the span open at the time.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import json
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from repro.obs.metrics import METRICS
+
 __all__ = ["Span", "SpanRecorder", "NullRecorder", "NULL", "QueryTrace",
-           "current", "using", "op_name"]
+           "current", "using", "op_name", "watch_compiles"]
 
 
 def op_name(first: int, last: int, kinds) -> str:
@@ -74,10 +94,26 @@ class Span:
         self.attrs.update(attrs)
 
 
-class _OpenSpan:
-    """Context manager for one span on one recorder."""
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
 
-    __slots__ = ("_rec", "_name", "_cat", "_attrs", "span")
+
+def _annotation(name: str):
+    """A profiler annotation named ``name``, not yet entered; ``None``
+    while jax is not imported (tracing never imports it)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
+
+
+class _OpenSpan:
+    """Context manager for one span on one recorder (and, with jax
+    imported, one profiler annotation of the same name)."""
+
+    __slots__ = ("_rec", "_name", "_cat", "_attrs", "_ann", "span")
 
     def __init__(self, rec: "SpanRecorder", name: str, cat: str,
                  attrs: Dict[str, Any]):
@@ -95,9 +131,14 @@ class _OpenSpan:
         rec.spans.append(sp)
         rec._stack.append(sp)
         self.span = sp
+        self._ann = ann = _annotation(self._name)
+        if ann is not None:
+            ann.__enter__()
         return sp
 
     def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         sp = self._rec._stack.pop()
         sp.t1 = time.monotonic_ns()
         return False
@@ -121,6 +162,15 @@ class SpanRecorder:
 
     def current(self) -> Optional[Span]:
         return self._stack[-1] if self._stack else None
+
+    def record(self, name: str, cat: str, t0: int, t1: int,
+               **attrs) -> None:
+        """Add a span that has already ended (``t0``/``t1`` monotonic ns)
+        under the span open now."""
+        self.spans.append(Span(name, cat, self._next,
+                               self._stack[-1].id if self._stack else None,
+                               t0, t1, rank=self.rank, attrs=attrs))
+        self._next += 1
 
 
 class _NullSpan:
@@ -181,6 +231,38 @@ def using(rec):
         yield rec
     finally:
         _TLS.rec = prev
+
+
+# ------------------------------------------------------- XLA compiles
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_WATCH_LOCK = threading.Lock()
+_watching = False
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    METRICS.inc("jax.compiles.total")
+    METRICS.inc("jax.compile_ms.total", duration * 1e3)
+    rec = current()
+    if rec.enabled:
+        t1 = time.monotonic_ns()
+        attrs = {"fun": kw["fun_name"]} if "fun_name" in kw else {}
+        rec.record("compile", "kernel", t1 - int(duration * 1e9), t1,
+                   **attrs)
+
+
+def watch_compiles() -> None:
+    """Register the process-wide XLA compile listener (idempotent). JAX
+    reports each compile on the thread that asked for it, so the span
+    lands under the op (and phase) that caused it."""
+    global _watching
+    with _WATCH_LOCK:
+        if _watching:
+            return
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _watching = True
 
 
 # ------------------------------------------------------------ query trace

@@ -562,6 +562,12 @@ class AggMap:
 _SEG_KERNELS: Dict[Tuple, Callable] = {}
 _SEG_KERNELS_CAP = 64
 _SEG_LOCK = threading.Lock()
+# the largest padded segment count reduced in the dense form; above it
+# the scatter form. The dense form's work grows with rows x segs, a TPU's
+# scatter is a serial loop over rows whatever segs is: on a TPU v5e, at
+# 2**21 rows of Q1's 11 columns, the dense form is the faster up to 8192
+# slots and the slower from 16384 (benchmarks/sweep_segment_reduce.py).
+_DENSE_SEGS_MAX = 8192
 
 
 def reset_segment_kernels() -> None:
@@ -573,40 +579,85 @@ def _pow2(n: int) -> int:
     return max(8, 1 << max(0, int(n - 1).bit_length()))
 
 
+def _segment_kernel(combiners: Tuple[str, ...],
+                    acc_dtypes: Sequence[np.dtype], segs: int) -> Callable:
+    """The jitted ``segment_reduce(inv, *vals)``: every accumulator column
+    reduced into ``segs`` slots in one device program; a row whose ``inv``
+    is out of range (the padding carries ``segs``) is dropped. Its form
+    follows ``segs``: up to ``_DENSE_SEGS_MAX`` a masked dense reduction
+    over all rows and slots (``where(inv == slot, v, 0)`` summed, or
+    ``v``/``±inf`` under min/max), which the device runs in parallel;
+    above it a ``.at[inv].add/min/max`` scatter."""
+    import jax
+    import jax.numpy as jnp
+
+    dense = segs <= _DENSE_SEGS_MAX
+
+    def segment_reduce(inv_d, *vals_d):
+        if dense:
+            # slots fit in int32, so the compare of every row with every
+            # slot skips the emulated 64-bit one
+            hit = (inv_d.astype(jnp.int32)[:, None]
+                   == jnp.arange(segs, dtype=jnp.int32))
+        outs = []
+        for comb, v, dt in zip(combiners, vals_d, acc_dtypes):
+            v = v.astype(dt)
+            init = {"sum": 0, "max": -jnp.inf, "min": jnp.inf}[comb]
+            if dense:
+                m = hit.reshape(hit.shape + (1,) * (v.ndim - 1))
+                masked = jnp.where(m, v[:, None], jnp.asarray(init, dt))
+                red = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min}[comb]
+                outs.append(red(masked, axis=0).astype(dt))
+            else:
+                acc = jnp.full((segs,) + v.shape[1:], init, dt)
+                red = {"sum": acc.at[inv_d].add, "max": acc.at[inv_d].max,
+                       "min": acc.at[inv_d].min}[comb]
+                outs.append(red(v, mode="drop"))
+        return tuple(outs)
+
+    return jax.jit(segment_reduce)
+
+
 def device_segment_reducer(combiners: Tuple[str, ...],
                            force: bool = False) -> Optional[Callable]:
     """The fused on-device pre-aggregation for ``expr_backend="jax"``: one
-    jitted kernel scatter-reducing every accumulator column of a partition
-    in a single call (``segment_sum``-style ``.at[inv].add/min/max`` under
-    ``enable_x64``, accumulator dtypes matching the host scatters). Group
-    discovery (``np.unique``) stays on host — it is what fixes the
-    deterministic key order — only the reduction itself runs on device.
-    Rows and segment counts are padded to power-of-two buckets
-    (out-of-range rows dropped by the scatter) so XLA retraces O(log²)
-    times, not once per partition shape.
+    jitted kernel (:func:`_segment_kernel`) reducing every accumulator
+    column of a partition in a single call, under ``enable_x64`` with
+    accumulator dtypes matching the host scatters (f64 floats, i64
+    integers and bools). Group discovery (``np.unique``) stays on host —
+    it is what fixes the deterministic key order — only the reduction
+    itself runs on device. Rows and segment counts are padded to
+    power-of-two buckets (padded rows carry ``inv = segs`` and land in no
+    slot) so XLA retraces O(log²) times, not once per partition shape.
 
-    Bit-identity with the host scatters is test-pinned where XLA lowers
-    the scatter to a sequential row-order accumulation (CPU, via the
-    forced tests below). Float scatter-add ordering on other accelerator
-    backends is XLA-implementation-defined, and a TPU emulates f64: on a
-    TPU v5e, TPC-H Q1's float sums differ from the host's in the last
-    bits (about 1e-11 relative, ``chip_smoke.py``). Min/max and
-    integer/count sums are order-free and stay exact.
+    The padded segment count alone picks the kernel's form: at most
+    ``_DENSE_SEGS_MAX`` slots, a masked dense reduction (a tree over rows,
+    parallel on the device); more, a scatter (on a TPU a serial loop over
+    rows). Each call counts one ``agg.device_reduce.dense.total`` or
+    ``agg.device_reduce.scatter.total``.
+
+    Integer and count sums, min and max are order-free, so both forms
+    give them bit-identical to the host scatters. Float sums: the dense
+    form adds in a tree, not in the host's row order, and a TPU emulates
+    f64 and orders its scatter as it likes, so float sums may differ
+    from the host's in the last bits: within 1e-12 relative in the CPU's
+    dense form (test-pinned); for TPC-H Q1 on a TPU v5e about 1e-14 in
+    the dense form, 3e-11 in the scatter form. The CPU's scatter form
+    accumulates in row order and is bit-identical (test-pinned).
 
     Like the physical planner's broadcast decision, the offload must win
     on modeled cost: XLA's *CPU* scatter is ~50x slower per element than
     ``np.add.at``, so on a CPU-only jax backend this returns ``None`` and
     pre-aggregation stays on the host scatters (set ``force=True`` — or
     ``REPRO_AGG_DEVICE=1`` in the environment — to offload regardless;
-    the equivalence tests do, to pin down bit-identity of the device
-    path). On an accelerator backend the device path engages by default.
+    the equivalence tests do, to pin down the device path's results). On
+    an accelerator backend the device path engages by default.
 
     The returned reducer itself returns ``None`` per call for non-numeric
     value dtypes (caller falls back to the numpy scatter)."""
     import os
 
     import jax
-    import jax.numpy as jnp
 
     if (not (force or os.environ.get("REPRO_AGG_DEVICE") == "1")
             and jax.default_backend() == "cpu"):
@@ -627,27 +678,13 @@ def device_segment_reducer(combiners: Tuple[str, ...],
         with _SEG_LOCK:
             kern = _SEG_KERNELS.get(key)
         if kern is None:
-            def segment_reduce(inv_d, *vals_d):
-                outs = []
-                for comb, v, dt in zip(combiners, vals_d, acc_dtypes):
-                    shape = (segs,) + v.shape[1:]
-                    if comb == "sum":
-                        acc = jnp.zeros(shape, dt)
-                        outs.append(acc.at[inv_d].add(
-                            v.astype(dt), mode="drop"))
-                    else:
-                        init = -jnp.inf if comb == "max" else jnp.inf
-                        acc = jnp.full(shape, init, dt)
-                        op = (acc.at[inv_d].max if comb == "max"
-                              else acc.at[inv_d].min)
-                        outs.append(op(v.astype(dt), mode="drop"))
-                return tuple(outs)
-
-            kern = jax.jit(segment_reduce)
+            kern = _segment_kernel(combiners, acc_dtypes, segs)
             with _SEG_LOCK:
                 while len(_SEG_KERNELS) >= _SEG_KERNELS_CAP:
                     _SEG_KERNELS.pop(next(iter(_SEG_KERNELS)))
                 _SEG_KERNELS[key] = kern
+        form = "dense" if segs <= _DENSE_SEGS_MAX else "scatter"
+        METRICS.inc(f"agg.device_reduce.{form}.total")
         rec = current()
         with rec.span("agg:put", cat="kernel"):
             inv_p = np.full(rows, segs, np.int64)

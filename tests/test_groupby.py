@@ -114,24 +114,132 @@ def test_tpch_q1_matrix_byte_identical(tmp_path):
             == (lines["shipdate"] <= 9400).sum())
 
 
-def test_device_segment_reducer_bit_identical_when_forced(monkeypatch):
-    """On a CPU jax backend the device scatter is cost-gated off; force it
-    on (REPRO_AGG_DEVICE=1) and pin down that the on-device segment
-    reduction is bit-identical to the host scatters — the property the
-    accelerator path relies on."""
-    from repro.core.relops import device_segment_reducer
+def _device_form_calls():
+    from repro.obs.metrics import METRICS
+    return {f: METRICS.counter(f"agg.device_reduce.{f}.total")
+            for f in ("dense", "scatter")}
+
+
+def _wide_rows(n_keys, seed):
+    """Rows whose ``k1`` takes ``n_keys`` values, each about twice."""
+    rng = np.random.default_rng(seed)
+    n = 2 * n_keys
+    return GRow.pack(k1=rng.integers(0, n_keys, n),
+                     k2=rng.choice([b"aa"], n),
+                     v1=rng.normal(0, 100, n),
+                     v2=rng.integers(-50, 50, n))
+
+
+@pytest.mark.parametrize("form", ["scatter", "dense"])
+def test_device_segment_reducer_bit_identical_when_forced(monkeypatch,
+                                                          form):
+    """On a CPU jax backend the device reducer is cost-gated off; force it
+    on (REPRO_AGG_DEVICE=1) and pin down its results against the host
+    scatters, in each form. The scatter form (more groups a partition
+    than ``_DENSE_SEGS_MAX``) accumulates in row order on the CPU: byte-
+    identical. The dense form adds floats in a tree: keys, counts,
+    integer sums, min and max stay byte-identical, float sums and means
+    agree within 1e-12 relative."""
+    from repro.core.relops import _DENSE_SEGS_MAX, device_segment_reducer
     assert device_segment_reducer(("sum",), force=True) is not None
-    records = _rows(500, seed=8)
+    parts = 3
+    records = (_wide_rows(2 * parts * _DENSE_SEGS_MAX, seed=8)
+               if form == "scatter" else _rows(500, seed=8))
     build = lambda ds: (ds.group_by("k1", "k2")  # noqa: E731
                           .agg(s=agg.sum("v1"), lo=agg.min("v1"),
                                hi=agg.max("v2"), m=agg.mean("v1"),
-                               n=agg.count()))
-    host = Session(num_partitions=3, expr_backend="numpy")
+                               n=agg.count(), t=agg.sum("v2")))
+    host = Session(num_partitions=parts, expr_backend="numpy")
     ref = build(host.load("g", records, GRow)).collect()
     monkeypatch.setenv("REPRO_AGG_DEVICE", "1")
-    dev = Session(num_partitions=3, expr_backend="jax")
+    dev = Session(num_partitions=parts, expr_backend="jax")
+    before = _device_form_calls()
     got = build(dev.load("g", records, GRow)).collect()
-    _assert_bytes_equal([ref, got])
+    after = _device_form_calls()
+    other = "dense" if form == "scatter" else "scatter"
+    # one call for each partition that holds rows, all in one form
+    assert after[form] > before[form]
+    assert after[other] == before[other]
+    if form == "scatter":
+        _assert_bytes_equal([ref, got])
+        return
+    floats = ("s", "m")
+    _assert_bytes_equal([{c: v for c, v in r.items() if c not in floats}
+                         for r in (ref, got)])
+    for c in floats:
+        x, y = np.asarray(ref[c]), np.asarray(got[c])
+        assert x.dtype == y.dtype == np.float64
+        np.testing.assert_allclose(y, x, rtol=1e-12, atol=0)
+
+
+def test_device_reducer_form_follows_the_segment_count():
+    """Up to ``_DENSE_SEGS_MAX`` padded slots the dense form, above it the
+    scatter form; each call counts once under its form."""
+    from repro.core.relops import _DENSE_SEGS_MAX, device_segment_reducer
+    red = device_segment_reducer(("sum", "max"), force=True)
+    for n, form in ((5, "dense"), (_DENSE_SEGS_MAX, "dense"),
+                    (_DENSE_SEGS_MAX + 1, "scatter")):
+        inv = np.arange(n)
+        vals = [np.ones(n, np.int64), np.arange(n, dtype=np.float64)]
+        before = _device_form_calls()
+        acc, hi = red(inv, n, vals)
+        after = _device_form_calls()
+        assert {f: after[f] - before[f] for f in after} == {
+            f: int(f == form) for f in after}
+        assert acc.tolist() == [1] * n and hi.tolist() == list(range(n))
+
+
+def _dense_case(seed):
+    """One partition's reducer inputs: 37 rows (padded to 64) into 11
+    slots, slots 3 and 10 empty; value columns of every accumulator
+    kind, one with a trailing dimension."""
+    from repro.core.relops import _DENSE_SEGS_MAX
+    rng = np.random.default_rng(seed)
+    n, rows = 11, 37
+    assert n <= _DENSE_SEGS_MAX
+    inv = rng.choice([s for s in range(n) if s not in (3, 10)], rows)
+    vals = {"i64": rng.integers(-1 << 40, 1 << 40, rows),
+            "i32": rng.integers(-1000, 1000, rows).astype(np.int32),
+            "bool": rng.random(rows) > 0.5,
+            "ones": np.ones(rows, np.int64),
+            "f64": rng.normal(0, 1e3, rows),
+            "f64x2": rng.normal(0, 1e3, (rows, 2)),
+            "f32": rng.normal(0, 1e3, rows).astype(np.float32)}
+    return inv, n, vals
+
+
+def test_dense_reducer_exact_accumulators_bit_identical_to_host():
+    from repro.core.relops import _COMBINE, device_segment_reducer
+    inv, n, vals = _dense_case(seed=21)
+    cols = [("sum", vals["i64"]), ("sum", vals["i32"]),
+            ("sum", vals["bool"]), ("sum", vals["ones"])]
+    cols += [(c, vals[k]) for c in ("min", "max")
+             for k in ("f64", "f64x2", "i64", "f32")]
+    combs = tuple(c for c, _ in cols)
+    got = device_segment_reducer(combs, force=True)(
+        inv, n, [v for _, v in cols])
+    for (comb, v), g in zip(cols, got):
+        want = _COMBINE[comb](None, inv, v, n)
+        assert g.dtype == want.dtype and g.shape == want.shape
+        assert g.tobytes() == want.tobytes(), (comb, v.dtype)
+    # empty slots keep the initial value; padded rows land nowhere
+    sums, mins, maxs = got[:4], got[4:8], got[8:]
+    assert all(a[[3, 10]].tolist() == [0, 0] for a in sums)
+    assert all(np.isposinf(a[[3, 10]]).all() for a in mins)
+    assert all(np.isneginf(a[[3, 10]]).all() for a in maxs)
+    assert got[3].sum() == len(inv)
+
+
+def test_dense_reducer_float_sums_within_1e12_of_host():
+    from repro.core.relops import _COMBINE, device_segment_reducer
+    inv, n, vals = _dense_case(seed=22)
+    cols = [vals["f64"], vals["f64x2"], vals["f32"]]
+    got = device_segment_reducer(("sum",) * 3, force=True)(inv, n, cols)
+    for v, g in zip(cols, got):
+        want = _COMBINE["sum"](None, inv, v, n)
+        assert g.dtype == want.dtype == np.float64
+        assert g.shape == want.shape
+        np.testing.assert_allclose(g, want, rtol=1e-12, atol=0)
 
 
 # ----------------------------------------------------------- edge cases

@@ -77,22 +77,50 @@ def test_q1_stage_core_compiles_for_v5e(one_chip, q1_programs, rows):
         rows * np.dtype(d).itemsize for d in core.core_dtypes)
 
 
+def _compile_segment_kernel(kern, one_chip, rows, val_sig):
+    args = [jax.ShapeDtypeStruct((rows,), np.int64, sharding=one_chip)]
+    args += [jax.ShapeDtypeStruct((rows,) + tuple(shape), np.dtype(dt),
+                                  sharding=one_chip)
+             for dt, shape in val_sig]
+    with jax.enable_x64(True):
+        lowered = kern.lower(*args)
+        compiled = lowered.compile()
+    return lowered, compiled
+
+
 @pytest.mark.parametrize("rows", PARTITION_BUCKETS)
 def test_q1_segment_reducer_compiles_for_v5e(one_chip, q1_programs, rows):
+    """Q1's four groups take the dense form: a masked reduction, no
+    scatter."""
     _, seg_kernels = q1_programs
     for (combiners, acc_dtypes, val_sig, _rows, segs), kern in \
             seg_kernels.items():
         # Q1: four sums, three means (a sum and a count each), one count
         assert len(combiners) == 11
-        args = [jax.ShapeDtypeStruct((rows,), np.int64, sharding=one_chip)]
-        args += [jax.ShapeDtypeStruct((rows,) + tuple(shape), np.dtype(dt),
-                                      sharding=one_chip)
-                 for dt, shape in val_sig]
-        with jax.enable_x64(True):
-            lowered = kern.lower(*args)
-            compiled = lowered.compile()
+        assert segs <= relops._DENSE_SEGS_MAX
+        lowered, compiled = _compile_segment_kernel(kern, one_chip, rows,
+                                                    val_sig)
+        assert "scatter" not in compiled.as_text()
         outs = jax.tree.leaves(lowered.out_info)
         assert [str(o.dtype) for o in outs] == list(acc_dtypes)
         assert all(o.shape == (segs,) for o in outs)
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes < 16 * 2 ** 30
+
+
+def test_scatter_segment_reducer_compiles_for_v5e(one_chip, q1_programs):
+    """A Q18-subquery-sized partition (about 375k orderkeys, segs 2**19)
+    takes the scatter form; Q1's accumulators at that bucket."""
+    _, seg_kernels = q1_programs
+    (combiners, acc_dtypes, val_sig, _, _), = seg_kernels
+    segs = 1 << 19
+    assert segs > relops._DENSE_SEGS_MAX
+    kern = relops._segment_kernel(combiners,
+                                  [np.dtype(d) for d in acc_dtypes], segs)
+    lowered, compiled = _compile_segment_kernel(kern, one_chip,
+                                                PARTITION_BUCKETS[0],
+                                                val_sig)
+    assert "scatter" in compiled.as_text()
+    outs = jax.tree.leaves(lowered.out_info)
+    assert [str(o.dtype) for o in outs] == list(acc_dtypes)
+    assert all(o.shape == (segs,) for o in outs)

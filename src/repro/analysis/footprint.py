@@ -6,8 +6,8 @@ two static sources the analyzer already has:
 
 * **cardinality** — the planner's row-estimate conventions
   (:func:`repro.core.physical.estimate_bytes` heritage: SCAN is the
-  stored row count, FILTER keeps ~half, AGG collapses to ~10%, TOPK caps
-  at k, FLATTEN fans out ~4×, JOIN carries the larger side);
+  stored row count, FILTER keeps ~half, AGG collapses to ~10%, TOPK and
+  SORT cap at k / limit, FLATTEN fans out ~4×, JOIN carries the larger side);
 * **width** — planlint's inferred per-edge dtypes
   (:func:`~repro.analysis.schema_pass.infer_dtypes`); columns the
   inference cannot type fall back to 8 bytes.
@@ -87,8 +87,8 @@ def _row_walk(prog: TCAPProgram, store) -> tuple:
             rows[op.out] = rows.get(op.in_list, 0.0) * _FLATTEN_FANOUT
         elif op.op == "AGG":
             rows[op.out] = rows.get(op.in_list, 0.0) * _AGG_REDUCTION
-        elif op.op == "TOPK":
-            k = float(op.info.get("k", 1))
+        elif op.op in ("TOPK", "SORT"):
+            k = float(op.info.get("k", op.info.get("limit", 1)))
             rows[op.out] = min(rows.get(op.in_list, 0.0), k)
         elif op.op == "JOIN":
             rows[op.out] = max(rows.get(op.in_list, 0.0),

@@ -28,7 +28,7 @@ hash-partitioned on; the pass threads facts forward:
 * AGG: where the ordered key-id tuple is a member of the live fact set,
   its exchange is redundant (**PL201**, elided); either way the output
   carries the key-tuple fact;
-* TOPK gathers to one rank — facts die.
+* TOPK and SORT gather to one rank — facts die.
 
 Column *values* are tracked by structural value ids so a fact follows the
 value, not the column name: an AGG key packed into a record column (the
@@ -224,9 +224,9 @@ def propagate_partitioning(prog: TCAPProgram,
             fact[op.out] = (frozenset({kvids})
                             if all(_usable(v) for v in kvids)
                             else _NO_FACTS)
-        elif op.op == "TOPK":
+        elif op.op in ("TOPK", "SORT"):
             for c in op.out_cols:
-                vid[(op.out, c)] = ("topk", i, c)
+                vid[(op.out, c)] = (op.op.lower(), i, c)
             fact[op.out] = _NO_FACTS  # global gather to one rank
         elif op.op == "OUTPUT":
             fact[op.out] = fact.get(op.in_list, _NO_FACTS)

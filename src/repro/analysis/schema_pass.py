@@ -282,6 +282,16 @@ def _schema_pass_loop(prog, store, diags, dt, tainted, consty,
                 dt[(op.out, out_c)] = dt.get((op.in_list, in_c))
                 if (op.in_list, in_c) in tainted:
                     tainted.add((op.out, out_c))
+        elif op.op == "SORT":
+            # the input's records, unpacked: one column per field
+            rd = dt.get((op.in_list, op.apply_cols[0]))
+            taint = (op.in_list, op.apply_cols[0]) in tainted
+            for c in op.out_cols:
+                d = (rd.fields[c][0] if rd is not None and rd.names
+                     and c in rd.names else None)
+                dt[(op.out, c)] = d.base if d is not None else None
+                if taint:
+                    tainted.add((op.out, c))
         elif op.op == "OUTPUT":
             output = {c: dt.get((op.in_list, c)) for c in op.apply_cols}
         # FILTER and JOIN are pure routing: copy_through covered them

@@ -17,8 +17,9 @@ from repro.core.lambdas import (LambdaArg, LambdaTerm, TypedLambdaArg,
 from repro.core.exprc import (EXPR_BACKENDS, FusedStage, build_steps,
                               kernel_cache_info, reset_kernel_cache)
 from repro.core.computations import (AggregateComp, Computation, JoinComp,
-                                     MultiSelectionComp, ScanSet,
-                                     SelectionComp, TopKComp, WriteSet)
+                                     MultiSelectionComp, OrderByComp,
+                                     ScanSet, SelectionComp, TopKComp,
+                                     WriteSet)
 from repro.core.tcap import TCAPOp, TCAPProgram, structural_signature
 from repro.core.compiler import compile_graph
 from repro.core.optimizer import (OptimizerReport, dead_column_elimination,
@@ -28,11 +29,12 @@ from repro.core.physical import PhysicalPlan, estimate_bytes, plan_physical
 from repro.core.executor import ExecStats, Executor, NaiveExecutor
 from repro.core.planner import ShardingPlan, make_plan
 from repro.core.aggregates import AGG_KINDS, AggTerm, agg
-from repro.core.dataset import Dataset, GroupedDataset
+from repro.core.dataset import Dataset, GroupedDataset, OrderedDataset
 from repro.core.session import Session
 
 __all__ = [
-    "Dataset", "GroupedDataset", "Session", "NameScope", "default_scope",
+    "Dataset", "GroupedDataset", "OrderedDataset", "Session", "NameScope",
+    "default_scope",
     "AGG_KINDS", "AggTerm", "agg",
     "structural_signature",
     "EXPR_BACKENDS", "FusedStage", "build_steps", "kernel_cache_info",
@@ -41,8 +43,8 @@ __all__ = [
     "make_lambda_from_member", "make_lambda_from_method",
     "make_lambda_from_self", "register_method", "METHOD_REGISTRY",
     "AggregateComp", "Computation", "JoinComp", "MultiSelectionComp",
-    "ScanSet", "SelectionComp", "TopKComp", "WriteSet", "TCAPOp",
-    "TCAPProgram", "compile_graph", "OptimizerReport",
+    "OrderByComp", "ScanSet", "SelectionComp", "TopKComp", "WriteSet",
+    "TCAPOp", "TCAPProgram", "compile_graph", "OptimizerReport",
     "dead_column_elimination", "eliminate_redundant_applies", "optimize",
     "push_filters_past_joins", "PhysicalPlan", "estimate_bytes",
     "plan_physical", "ExecStats", "Executor", "NaiveExecutor",

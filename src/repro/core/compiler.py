@@ -1,7 +1,7 @@
 """TCAP compiler (paper §5): calls each Computation's lambda-term
 construction functions and flattens the resulting expression trees into a
 TCAP program — one APPLY per lambda node, FILTERs for selections, HASH/JOIN
-for joins, AGG/TOPK/OUTPUT sinks.
+for joins, AGG/TOPK/SORT/OUTPUT sinks.
 
 Join selections are decomposed into conjuncts; equality conjuncts whose two
 sides each depend on a single (distinct) input become hash-join keys, the
@@ -31,9 +31,11 @@ import numpy as np
 
 from repro.core.aggregates import AGG_KINDS
 from repro.core.computations import (AggregateComp, Computation, JoinComp,
-                                     MultiSelectionComp, ScanSet,
-                                     SelectionComp, TopKComp, WriteSet)
-from repro.core.lambdas import LambdaArg, LambdaTerm, TypedLambdaArg, constant
+                                     MultiSelectionComp, OrderByComp,
+                                     ScanSet, SelectionComp, TopKComp,
+                                     WriteSet)
+from repro.core.lambdas import (LambdaArg, LambdaTerm, TypedLambdaArg,
+                                UnknownColumnError, constant)
 from repro.core.tcap import TCAPOp, TCAPProgram
 
 __all__ = ["compile_graph"]
@@ -278,6 +280,30 @@ def compile_graph(sink: Computation) -> TCAPProgram:
                                comp=comp.name, stage="topk",
                                info={"type": "topk", "k": str(comp.k)}))
             return out, ("score", "payload")
+
+        if isinstance(comp, OrderByComp):
+            schema = comp.inputs[0].output_schema
+            if schema is None:
+                raise ValueError(f"{comp.name}: ORDER BY needs a typed "
+                                 "input (a Record schema names its fields)")
+            fields = tuple(schema.fields)
+            for f, _ in comp.keys:
+                if f not in fields:
+                    raise UnknownColumnError(f, schema)
+            in_list, in_col = record_stream(comp.name, *rec(comp.inputs[0]))
+            out = namer.vlist("Srt")
+            # one column per field of the input schema: the result keeps
+            # the input's records, unpacked into named columns as an AGG's
+            prog.append(TCAPOp(out=out, out_cols=fields, op="SORT",
+                               in_list=in_list, apply_cols=(in_col,),
+                               copy_cols=(), comp=comp.name, stage="sort",
+                               info={"type": "sort",
+                                     "keys": ",".join(f for f, _ in
+                                                      comp.keys),
+                                     "desc": ",".join(str(int(d)) for _, d
+                                                      in comp.keys),
+                                     "limit": str(comp.limit)}))
+            return out, fields
 
         raise TypeError(f"cannot compile computation {comp!r}")
 

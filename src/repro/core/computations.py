@@ -17,7 +17,8 @@ from repro.core.lambdas import LambdaArg, LambdaTerm
 from repro.core.naming import NameScope, default_scope
 
 __all__ = ["Computation", "ScanSet", "WriteSet", "SelectionComp",
-           "MultiSelectionComp", "JoinComp", "AggregateComp", "TopKComp"]
+           "MultiSelectionComp", "JoinComp", "AggregateComp", "TopKComp",
+           "OrderByComp"]
 
 
 class Computation(abc.ABC):
@@ -222,3 +223,18 @@ class TopKComp(Computation):
     @abc.abstractmethod
     def get_payload(self, arg: LambdaArg) -> LambdaTerm:
         ...
+
+
+class OrderByComp(Computation):
+    """``ORDER BY ... LIMIT n`` over a typed input: the first ``limit``
+    records in the order of ``keys``, each ``(field name, descending)``,
+    with the input's record schema. Implemented as a per-partition
+    pre-selection and a gather-merge (:func:`~repro.core.relops
+    .order_select` fixes the order, ties included)."""
+
+    def __init__(self, keys: Sequence[Tuple[str, bool]], limit: int,
+                 name: Optional[str] = None,
+                 scope: Optional[NameScope] = None):
+        super().__init__(name, scope)
+        self.keys = tuple((str(f), bool(d)) for f, d in keys)
+        self.limit = int(limit)

@@ -3,8 +3,8 @@ as a chainable front-end.
 
 A :class:`Dataset` is an immutable description of a query: each chain method
 (``filter`` / ``select`` / ``flat_map`` / ``join`` / ``group_by(...).agg`` /
-``aggregate`` / ``top_k`` / ``write``) returns a new handle holding one more
-plan node.
+``aggregate`` / ``top_k`` / ``order_by(...).limit`` / ``write``) returns a
+new handle holding one more plan node.
 Nothing runs until a terminal — ``collect()`` / ``to_numpy()`` — at which
 point the owning :class:`~repro.core.session.Session` synthesizes the
 corresponding :class:`~repro.core.computations.Computation` subclass graph,
@@ -42,8 +42,9 @@ import numpy as np
 
 from repro.core.aggregates import AggTerm, agg
 from repro.core.computations import (AggregateComp, Computation, JoinComp,
-                                     MultiSelectionComp, ScanSet,
-                                     SelectionComp, TopKComp, WriteSet)
+                                     MultiSelectionComp, OrderByComp,
+                                     ScanSet, SelectionComp, TopKComp,
+                                     WriteSet)
 from repro.core.lambdas import (LambdaArg, LambdaTerm, TypedLambdaArg,
                                 UnknownColumnError, constant, make_lambda,
                                 make_lambda_from_member,
@@ -52,7 +53,7 @@ from repro.core.relops import sum_acc_dtype
 from repro.objectmodel.schema import (Field, group_schema, pair_field_map,
                                       pair_schema)
 
-__all__ = ["Dataset", "GroupedDataset"]
+__all__ = ["Dataset", "GroupedDataset", "OrderedDataset"]
 
 LambdaSpec = Union[str, Callable[..., LambdaTerm], None]
 
@@ -171,15 +172,23 @@ class _TopK:
     payload: LambdaSpec
 
 
+@dataclasses.dataclass(frozen=True)
+class _OrderBy:
+    parent: Any
+    keys: Tuple[Tuple[str, bool], ...]  # (field name, descending)
+    limit: int
+
+
 def _node_schema(node) -> Optional[type]:
     """The record schema of a plan node's output, when statically known:
-    filters preserve it, identity selects preserve it, the default join
-    projection introduces the pair schema, grouped aggregations introduce
-    their synthesized group schema; projections through arbitrary lambdas
-    yield fresh (unknown) record types."""
+    filters and ``order_by().limit()`` preserve it, identity selects
+    preserve it, the default join projection introduces the pair schema,
+    grouped aggregations introduce their synthesized group schema;
+    projections through arbitrary lambdas yield fresh (unknown) record
+    types."""
     if isinstance(node, _Scan):
         return node.schema
-    if isinstance(node, _Filter):
+    if isinstance(node, (_Filter, _OrderBy)):
         return _node_schema(node.parent)
     if isinstance(node, _Select):
         return _node_schema(node.parent) if node.proj is None else None
@@ -406,6 +415,44 @@ class Dataset:
         _validate_spec(payload, (self.schema,))
         return self._derive(_TopK(self._node, int(k), score, payload))
 
+    def order_by(self, *keys) -> "OrderedDataset":
+        """``ORDER BY``, completed by :meth:`OrderedDataset.limit`::
+
+            ds.order_by(("revenue", "desc"), "orderdate").limit(10)
+
+        Each key is a field name of this dataset's schema, ascending, or
+        a ``(name, "asc" | "desc")`` pair. The result keeps the records
+        and their schema; ``collect()`` returns one column per field, in
+        schema order. Ties on every listed key are broken by the other
+        fields in schema order, each ascending, on every backend and
+        runtime; NaN sorts above every number. Needs a typed dataset; an
+        ``order_by`` without ``limit`` (a full distributed sort) is not
+        offered."""
+        schema = self.schema
+        if schema is None:
+            raise ValueError(
+                "order_by() needs a typed dataset (load it with a Record "
+                "schema, or order a group_by().agg() or default join)")
+        if not keys:
+            raise ValueError("order_by() needs at least one key")
+        parsed = []
+        for k in keys:
+            if isinstance(k, str):
+                k = (k, "asc")
+            if (not isinstance(k, tuple) or len(k) != 2
+                    or not isinstance(k[0], str)
+                    or k[1] not in ("asc", "desc")):
+                raise TypeError(f"order_by() keys are field names or "
+                                f"(name, 'asc'|'desc') pairs, got {k!r}")
+            if k[0] not in schema.field_set:
+                raise UnknownColumnError(k[0], schema)
+            parsed.append((k[0], k[1] == "desc"))
+        names = [n for n, _ in parsed]
+        if len(set(names)) != len(names):
+            raise ValueError(f"order_by() keys must be distinct, got "
+                             f"{names}")
+        return OrderedDataset(self, tuple(parsed))
+
     def write(self, set_name: str) -> "Dataset":
         """Name the output set; ``collect()`` materializes the result there
         (structured record array) if the set does not already exist."""
@@ -521,6 +568,25 @@ class GroupedDataset:
         return ds._grouped_agg(self._keys, tuple(outputs.items()))
 
 
+class OrderedDataset:
+    """The intermediate handle of :meth:`Dataset.order_by`: holds the
+    ordering, waiting for :meth:`limit`."""
+
+    def __init__(self, ds: Dataset, keys: Tuple[Tuple[str, bool], ...]):
+        self._ds = ds
+        self._keys = keys
+
+    def limit(self, n: int) -> Dataset:
+        """The first ``n`` records in the ordering (all of them when fewer
+        exist)."""
+        if isinstance(n, bool) or not isinstance(n, (int, np.integer)) \
+                or n < 0:
+            raise ValueError(f"limit() takes a whole number >= 0, got "
+                             f"{n!r}")
+        return self._ds._derive(_OrderBy(self._ds._node, self._keys,
+                                         int(n)))
+
+
 # ----------------------------------------------------- graph synthesis
 def _synthesize(sess, node) -> Computation:
     scope = sess.scope
@@ -632,6 +698,13 @@ def _synthesize(sess, node) -> Computation:
 
         comp = _FluentTopK(node.k, name=scope.fresh("TopK"), scope=scope)
         comp.set_input(upstream)
+        return comp
+
+    if isinstance(node, _OrderBy):
+        comp = OrderByComp(node.keys, node.limit,
+                           name=scope.fresh("OrderBy"), scope=scope)
+        comp.set_input(_synthesize(sess, node.parent))
+        comp.output_schema = _node_schema(node)
         return comp
 
     raise TypeError(f"unknown plan node {node!r}")

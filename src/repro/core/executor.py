@@ -8,7 +8,9 @@ distributed semantics are simulated with P logical partitions on one host:
 * **AGG** — PC's two-stage plan: per-partition *pre-aggregation* into maps
   ("combiner pages"), shuffle partials by key hash, final aggregation;
 * **TOPK** — per-partition top-k, then a global merge (the paper's
-  TopJaccard pattern).
+  TopJaccard pattern);
+* **SORT** — ``ORDER BY ... LIMIT``: per-partition pre-selection, then a
+  global gather-merge.
 
 The per-partition operator kernels live in :mod:`repro.core.relops` and are
 shared verbatim with the distributed worker runtime (:mod:`repro.dist`);
@@ -37,9 +39,11 @@ from repro.core.optimizer import OptimizerReport, optimize
 from repro.core.physical import PhysicalPlan, plan_physical
 from repro.core.relops import (AggMap, AggSpec, assemble_output,
                                batch_kernel, batch_topk, bytes_of,
-                               concat_batches, device_segment_reducer,
-                               greedy_page_placement, merge_topk,
-                               probe_join, split_by_hash)
+                               concat_batches, count_shuffle,
+                               device_segment_reducer,
+                               greedy_page_placement, merge_ordered,
+                               merge_topk, order_partition, probe_join,
+                               split_by_hash)
 from repro.core.tcap import TCAPOp, TCAPProgram
 from repro.obs.trace import NULL, current, op_name, using
 from repro.objectmodel.store import PagedStore
@@ -163,6 +167,8 @@ class Executor:
                             elide=id(op) in plan.agg_elide)
                     elif op.op == "TOPK":
                         data[op.out] = self._topk(op, data[op.in_list])
+                    elif op.op == "SORT":
+                        data[op.out] = self._order(op, data[op.in_list])
                     elif op.op == "OUTPUT":
                         result = self._output(op, data[op.in_list])
                     else:
@@ -212,8 +218,8 @@ class Executor:
             with current().span(f"x:bcast:{i}:build", cat="exchange",
                                 tag=f"{i}:build") as sp:
                 build_all = concat_batches([vl for bl in right for vl in bl])
-                self.stats.shuffle_bytes += (bytes_of(build_all)
-                                             * max(0, self.P - 1))
+                count_shuffle(self.stats,
+                              bytes_of(build_all) * max(0, self.P - 1))
             sp.set(bytes=self.stats.shuffle_bytes - sb0)
             rparts = [build_all] * self.P
             lparts = [concat_batches(p) for p in left]
@@ -252,7 +258,7 @@ class Executor:
                         if sub is None:
                             continue
                         if p != pi:
-                            self.stats.shuffle_bytes += bytes_of(sub)
+                            count_shuffle(self.stats, bytes_of(sub))
                         buckets[p].append(sub)
             out = [concat_batches(b) for b in buckets]
         sp.set(bytes=self.stats.shuffle_bytes - sb0)
@@ -300,8 +306,8 @@ class Executor:
                     with rec.span("agg:merge", cat="kernel"):
                         for split in splits:
                             if split[p].data:
-                                self.stats.shuffle_bytes += \
-                                    split[p].nbytes()
+                                count_shuffle(self.stats,
+                                              split[p].nbytes())
                                 final.merge(split[p])
             sp.set(bytes=self.stats.shuffle_bytes - sb0)
         out: List[List[VectorList]] = [[] for _ in range(self.P)]
@@ -322,6 +328,15 @@ class Executor:
                 best_p.append(pay)
         out: List[List[VectorList]] = [[] for _ in range(self.P)]
         merged = merge_topk(op, best_s, best_p)
+        if merged is not None:
+            out[0].append(merged)
+        return out
+
+    def _order(self, op: TCAPOp, parts) -> List[List[VectorList]]:
+        cands = [c for c in (order_partition(op, b) for b in parts)
+                 if c is not None]
+        out: List[List[VectorList]] = [[] for _ in range(self.P)]
+        merged = merge_ordered(op, cands)
         if merged is not None:
             out[0].append(merged)
         return out

@@ -278,7 +278,7 @@ def dead_column_elimination(prog: TCAPProgram
     cols_removed = ops_removed = 0
     for op in reversed(ops):
         need_out = needed.get(op.out, set())
-        if op.op in ("OUTPUT", "AGG", "TOPK"):
+        if op.op in ("OUTPUT", "AGG", "TOPK", "SORT"):
             need_out = set(op.out_cols)
         true_new = op.new_cols  # capture BEFORE trimming copy_cols
         if op.op == "APPLY" and op.info.get("type") in (*_CSEABLE, "rename"):
@@ -300,7 +300,7 @@ def dead_column_elimination(prog: TCAPProgram
         cols_removed += (len(op.copy_cols) - len(keep_copy)
                          + len(op.copy_cols2) - len(keep_copy2))
         op.copy_cols, op.copy_cols2 = keep_copy, keep_copy2
-        if op.op in ("SCAN", "AGG", "TOPK"):
+        if op.op in ("SCAN", "AGG", "TOPK", "SORT"):
             pass  # source/sink column sets are fixed
         else:
             op.out_cols = tuple(c for c in op.out_cols

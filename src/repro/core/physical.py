@@ -13,7 +13,7 @@ Two decisions mirror the paper exactly:
    fraction of *both* sides — broadcast must win on modeled bytes moved,
    not just clear the absolute threshold.
 2. **Pipeline decomposition** — the TCAP DAG is split into pipelines at
-   *pipe sinks* (JOIN build sides, AGG, TOPK, OUTPUT); each pipeline runs
+   *pipe sinks* (JOIN build sides, AGG, TOPK, SORT, OUTPUT); each pipeline runs
    stage-fused over vector lists.
 """
 from __future__ import annotations
@@ -133,7 +133,7 @@ def split_pipelines(prog: TCAPProgram) -> List[List[TCAPOp]]:
     cur: List[TCAPOp] = []
     for op in prog.ops:
         cur.append(op)
-        if op.op in ("JOIN", "AGG", "TOPK", "OUTPUT", "FLATTEN"):
+        if op.op in ("JOIN", "AGG", "TOPK", "SORT", "OUTPUT", "FLATTEN"):
             pipelines.append(cur)
             cur = []
     if cur:

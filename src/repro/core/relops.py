@@ -28,6 +28,10 @@ Kernels:
   shared by the local scan partitioner and ``dist.placement``;
 * :func:`batch_topk` / :func:`merge_topk` — per-partition top-k and the
   global gather-merge;
+* :func:`order_partition` / :func:`merge_ordered` — ``ORDER BY ... LIMIT``:
+  per-partition pre-selection and the gather-merge, both in the total
+  order :func:`order_select` fixes;
+* :func:`count_shuffle` — every exchange's byte accounting;
 * :func:`assemble_output` — the OUTPUT contract (column concat in
   partition-then-batch order, row count, single-column write-back);
 * :func:`concat_batches` / :func:`bytes_of` — glue.
@@ -49,10 +53,12 @@ from repro.obs.trace import current, watch_compiles
 from repro.objectmodel.vectorlist import VectorList
 
 __all__ = [
-    "AggMap", "AggSpec", "assemble_output", "batch_kernel", "batch_topk",
-    "bytes_of", "concat_batches", "device_segment_reducer",
-    "greedy_page_placement", "hash_col", "merge_topk", "probe_join",
-    "split_by_hash", "stable_key_hash", "stage_eval",
+    "AggMap", "AggSpec", "SHUFFLE_COUNTER", "assemble_output",
+    "batch_kernel", "batch_topk", "bytes_of", "concat_batches",
+    "count_shuffle", "device_segment_reducer", "greedy_page_placement",
+    "hash_col", "merge_ordered", "merge_topk", "order_partition",
+    "order_select", "probe_join", "split_by_hash", "stable_key_hash",
+    "stage_eval",
 ]
 
 _FNV_OFFSET = 0xcbf29ce484222325
@@ -261,27 +267,34 @@ def split_by_hash(vl: VectorList, hash_name: str, P: int
 def probe_join(op: TCAPOp, lvl: VectorList, rvl: VectorList
                ) -> Optional[Tuple[VectorList, int]]:
     """Sort-probe equi-join of two co-partitioned sides; returns the joined
-    batch and its row count, or ``None`` when either side is empty."""
+    batch and its row count, or ``None`` when either side is empty. Its
+    three phases record ``join:build`` (ordering the build side's codes),
+    ``join:probe`` (the searches and the row indices) and ``join:gather``
+    (both sides' columns copied into the result)."""
     lh, rh = op.apply_cols[0], op.apply_cols2[0]
     if lvl.num_rows in (None, 0) or rvl.num_rows in (None, 0):
         return None
-    lcode = np.asarray(lvl[lh])
-    rcode = np.asarray(rvl[rh])
-    order = np.argsort(rcode, kind="stable")
-    rsorted = rcode[order]
-    lo = np.searchsorted(rsorted, lcode, "left")
-    hi = np.searchsorted(rsorted, lcode, "right")
-    counts = hi - lo
-    l_idx = np.repeat(np.arange(len(lcode)), counts)
-    starts = np.repeat(lo, counts)
-    within = np.arange(len(starts)) - np.repeat(
-        np.cumsum(counts) - counts, counts)
-    r_idx = order[starts + within]
-    res = VectorList()
-    for c in op.copy_cols:
-        res.append(c, np.asarray(lvl[c])[l_idx])
-    for c in op.copy_cols2:
-        res.append(c, np.asarray(rvl[c])[r_idx])
+    rec = current()
+    with rec.span("join:build", cat="kernel"):
+        rcode = np.asarray(rvl[rh])
+        order = np.argsort(rcode, kind="stable")
+        rsorted = rcode[order]
+    with rec.span("join:probe", cat="kernel"):
+        lcode = np.asarray(lvl[lh])
+        lo = np.searchsorted(rsorted, lcode, "left")
+        hi = np.searchsorted(rsorted, lcode, "right")
+        counts = hi - lo
+        l_idx = np.repeat(np.arange(len(lcode)), counts)
+        starts = np.repeat(lo, counts)
+        within = np.arange(len(starts)) - np.repeat(
+            np.cumsum(counts) - counts, counts)
+        r_idx = order[starts + within]
+    with rec.span("join:gather", cat="kernel"):
+        res = VectorList()
+        for c in op.copy_cols:
+            res.append(c, np.asarray(lvl[c])[l_idx])
+        for c in op.copy_cols2:
+            res.append(c, np.asarray(rvl[c])[r_idx])
     return res, len(l_idx)
 
 
@@ -728,6 +741,75 @@ def merge_topk(op: TCAPOp, best_s: Sequence[np.ndarray],
     return VectorList({"score": s[idx], "payload": p[idx]})
 
 
+# --------------------------------------------------------------- order by
+def order_select(rec: np.ndarray, keys: Sequence[Tuple[str, bool]],
+                 limit: int) -> np.ndarray:
+    """The first ``limit`` records of the structured array ``rec`` in the
+    order of ``keys``, each ``(field, descending)``. Ties on every listed
+    key are broken by the record's other fields in schema order, each
+    ascending, so which rows are kept and their order depend only on the
+    rows: never on the backend, the runtime, the partitioning or the
+    batches. NaN sorts above every number (last ascending, first
+    descending); a vector field compares element by element."""
+    listed = {f for f, _ in keys}
+    rest = [(f, False) for f in rec.dtype.names if f not in listed]
+    cols = []  # most significant first
+    for f, descending in (*keys, *rest):
+        c = rec[f]
+        for part in ([c] if c.ndim == 1 else c.reshape(len(c), -1).T):
+            if descending:
+                # ranks order every dtype alike, bytes included
+                part = -np.unique(part, return_inverse=True)[1].reshape(-1)
+            cols.append(part)
+    return rec[np.lexsort(cols[::-1])[:limit]]
+
+
+def _order_spec(op: TCAPOp) -> Tuple[Tuple[Tuple[str, bool], ...], int]:
+    keys = op.info["keys"].split(",")
+    desc = [d == "1" for d in op.info["desc"].split(",")]
+    return tuple(zip(keys, desc)), int(op.info["limit"])
+
+
+def order_partition(op: TCAPOp, batches: Sequence[VectorList]
+                    ) -> Optional[np.ndarray]:
+    """A SORT op's pre-selection over one partition's batches (span
+    ``sort:select``): its first ``limit`` records, or ``None`` for a
+    partition without batches."""
+    if not batches:
+        return None
+    with current().span("sort:select", cat="kernel"):
+        rec = np.concatenate([np.asarray(vl[op.apply_cols[0]])
+                              for vl in batches])
+        return order_select(rec, *_order_spec(op))
+
+
+def merge_ordered(op: TCAPOp, candidates: Sequence[np.ndarray]
+                  ) -> Optional[VectorList]:
+    """A SORT op's gather-merge of the partitions' pre-selections (span
+    ``sort:merge``): the first ``limit`` records overall, unpacked into
+    one column per field, named as the op's output columns."""
+    if not candidates:
+        return None
+    with current().span("sort:merge", cat="kernel"):
+        rec = order_select(np.concatenate(list(candidates)),
+                           *_order_spec(op))
+        return VectorList({f: np.ascontiguousarray(rec[f])
+                           for f in op.out_cols})
+
+
+# --------------------------------------------------------------- exchange
+#: the process-wide count of the bytes exchanges put on the wire
+SHUFFLE_COUNTER = "exchange.shuffle.bytes.total"
+
+
+def count_shuffle(stats, nbytes: int) -> None:
+    """Account ``nbytes`` an exchange moved: the query's
+    ``stats.shuffle_bytes`` and, in the process that moved them,
+    :data:`SHUFFLE_COUNTER`."""
+    stats.shuffle_bytes += nbytes
+    METRICS.inc(SHUFFLE_COUNTER, nbytes)
+
+
 # ----------------------------------------------------------------- output
 def assemble_output(op: TCAPOp, batches: Sequence[VectorList], stats,
                     store, write_outputs: bool) -> Dict[str, np.ndarray]:
@@ -770,10 +852,13 @@ def greedy_page_placement(page_bytes: Sequence[int], P: int) -> List[int]:
 
 # ------------------------------------------------------------------- glue
 def concat_batches(batches: Sequence[VectorList]) -> VectorList:
-    out: Optional[VectorList] = None
-    for b in batches:
-        out = b if out is None else out.concat(b)
-    return out if out is not None else VectorList()
+    """The batches' rows in order, as one batch: one concatenation per
+    column (copying pairwise would copy the rows once per batch)."""
+    batches = list(batches)
+    if len(batches) <= 1:
+        return batches[0] if batches else VectorList()
+    return VectorList({c: np.concatenate([np.asarray(b[c]) for b in batches])
+                       for c in batches[0].names})
 
 
 def bytes_of(vl: VectorList) -> int:

@@ -19,7 +19,7 @@ __all__ = ["TCAPOp", "TCAPProgram", "structural_signature"]
 class TCAPOp:
     out: str  # output vector-list name
     out_cols: Tuple[str, ...]
-    op: str  # SCAN|APPLY|FILTER|HASH|JOIN|AGG|FLATTEN|TOPK|OUTPUT
+    op: str  # SCAN|APPLY|FILTER|HASH|JOIN|AGG|FLATTEN|TOPK|SORT|OUTPUT
     in_list: str = ""
     apply_cols: Tuple[str, ...] = ()
     copy_cols: Tuple[str, ...] = ()

@@ -4,8 +4,9 @@ Patterns (each operating on page blocks, tagged by TCAP op index so
 concurrent exchanges of one program never interleave):
 
 * :func:`exchange_partitions` — hash-partition shuffle (JOIN sides, one
-  call per side): every worker sends each peer that peer's bucket of
-  sub-batches and keeps its own bucket unserialized (locality is free);
+  call per side): every worker splits its batches by key hash, sends each
+  peer that peer's bucket of sub-batches and keeps its own bucket
+  unserialized (locality is free);
 * :func:`all_gather` — broadcast: every worker replicates its batches to
   all peers (small-side joins; serialized once, shipped P-1 times);
 * :func:`gather_to` — gather-merge: everyone ships to one root (TOPK's
@@ -33,6 +34,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.executor import ExecStats
+from repro.core.relops import concat_batches, count_shuffle, split_by_hash
 from repro.dist.protocol import (ABORT, DRIVER, decode_batch, encode_batch,
                                  read_frame, write_frame)
 from repro.obs.trace import current
@@ -147,20 +149,27 @@ class SocketTransport:
 
 
 # ------------------------------------------------------------- patterns
-def exchange_partitions(tr, P: int, tag: str,
-                        buckets: List[List[VectorList]],
-                        stats: ExecStats) -> List[List[VectorList]]:
-    """Hash-partition shuffle. ``buckets[p]`` is what this worker routed to
-    partition ``p`` (sub-batches in batch order). Returns, per source rank,
-    the sub-batches that landed here — own bucket stays unserialized."""
+def exchange_partitions(tr, P: int, tag: str, batches: List[VectorList],
+                        hash_name: str, stats: ExecStats) -> VectorList:
+    """Hash-partition shuffle of this worker's ``batches`` by the column
+    ``hash_name`` (``hash % P``, :func:`~repro.core.relops.split_by_hash`).
+    Returns the rows that landed here, concatenated in (source rank,
+    batch) order — own bucket stays unserialized. The split, the
+    transfers and the concatenation all sit inside the exchange span, as
+    in the local executor's shuffle."""
     rank = tr.rank
     sb0 = stats.shuffle_bytes
     with current().span(f"x:shuffle:{tag}", cat="exchange", tag=tag) as sp:
+        buckets: List[List[VectorList]] = [[] for _ in range(P)]
+        for vl in batches:
+            for p, sub in enumerate(split_by_hash(vl, hash_name, P)):
+                if sub is not None:
+                    buckets[p].append(sub)
         for dst in range(P):
             if dst == rank:
                 continue
             blocks = [encode_batch(vl) for vl in buckets[dst]]
-            stats.shuffle_bytes += sum(b.nbytes for b in blocks)
+            count_shuffle(stats, sum(b.nbytes for b in blocks))
             tr.send(dst, tag, blocks)
         inbox: List[List[VectorList]] = []
         for src in range(P):
@@ -168,8 +177,9 @@ def exchange_partitions(tr, P: int, tag: str,
                 inbox.append(buckets[rank])
             else:
                 inbox.append([decode_batch(b) for b in tr.recv(src, tag)])
+        out = concat_batches([vl for src in inbox for vl in src])
     sp.set(bytes=stats.shuffle_bytes - sb0)
-    return inbox
+    return out
 
 
 def all_gather(tr, P: int, tag: str, batches: List[VectorList],
@@ -185,7 +195,7 @@ def all_gather(tr, P: int, tag: str, batches: List[VectorList],
                 continue
             if blocks is None:
                 blocks = [encode_batch(vl) for vl in batches]
-            stats.shuffle_bytes += sum(b.nbytes for b in blocks)
+            count_shuffle(stats, sum(b.nbytes for b in blocks))
             tr.send(dst, tag, blocks)
         out = [batches if src == rank else
                [decode_batch(b) for b in tr.recv(src, tag)]
@@ -205,7 +215,7 @@ def gather_to(tr, P: int, tag: str, root: int,
     with current().span(f"x:gather:{tag}", cat="exchange", tag=tag) as sp:
         if rank != root:
             blocks = [encode_batch(vl) for vl in batches]
-            stats.shuffle_bytes += sum(b.nbytes for b in blocks)
+            count_shuffle(stats, sum(b.nbytes for b in blocks))
             tr.send(root, tag, blocks)
             out = None
         else:

@@ -11,6 +11,7 @@ plan stages across workers:
 * AGG — pre-aggregate locally, ``exchange_partitions`` of the partial maps
   by key hash, final merge;
 * TOPK — local per-batch top-k, ``gather_to`` worker 0, global merge there;
+* SORT — local pre-selection, ``gather_to`` worker 0, global merge there;
 * OUTPUT — ``gather_to`` the driver.
 
 Because placement is the same greedy-by-bytes rule the local executor
@@ -32,8 +33,9 @@ from repro.core.executor import ExecStats
 from repro.core.exprc import FusedStage, build_steps
 from repro.core.physical import PhysicalPlan, plan_from_wire
 from repro.core.relops import (AggMap, AggSpec, batch_kernel, batch_topk,
-                               concat_batches, device_segment_reducer,
-                               merge_topk, probe_join, split_by_hash)
+                               concat_batches, count_shuffle,
+                               device_segment_reducer, merge_ordered,
+                               merge_topk, order_partition, probe_join)
 from repro.core.tcap import TCAPOp, TCAPProgram
 from repro.dist.exchange import (PeerAborted, SocketTransport, all_gather,
                                  exchange_partitions, gather_to)
@@ -118,6 +120,8 @@ class WorkerRuntime:
                         elide=id(op) in plan.agg_elide)
                 elif op.op == "TOPK":
                     data[op.out] = self._topk(op, i, data[op.in_list])
+                elif op.op == "SORT":
+                    data[op.out] = self._order(op, i, data[op.in_list])
                 elif op.op == "OUTPUT":
                     self._output(op, i, data[op.in_list])
                 else:
@@ -161,29 +165,20 @@ class WorkerRuntime:
                 self.stats.exchanges_elided += 1
                 lvl = concat_batches(left)
             else:
-                lvl = self._shuffle_side(op.apply_cols[0], f"{i}:L", left)
+                lvl = exchange_partitions(self.tr, self.P, f"{i}:L", left,
+                                          op.apply_cols[0], self.stats)
             if "R" in elide:
                 self.stats.exchanges_elided += 1
                 rvl = concat_batches(right)
             else:
-                rvl = self._shuffle_side(op.apply_cols2[0], f"{i}:R", right)
+                rvl = exchange_partitions(self.tr, self.P, f"{i}:R", right,
+                                          op.apply_cols2[0], self.stats)
         probed = probe_join(op, lvl, rvl)
         if probed is None:
             return []
         res, n = probed
         self.stats.rows_joined += n
         return [res]
-
-    def _shuffle_side(self, hash_name: str, tag: str,
-                      batches: List[VectorList]) -> VectorList:
-        buckets: List[List[VectorList]] = [[] for _ in range(self.P)]
-        for vl in batches:
-            for p, sub in enumerate(split_by_hash(vl, hash_name, self.P)):
-                if sub is not None:
-                    buckets[p].append(sub)
-        inbox = exchange_partitions(self.tr, self.P, tag, buckets,
-                                    self.stats)
-        return concat_batches([vl for src in inbox for vl in src])
 
     def _aggregate(self, op: TCAPOp, i: int, batches: List[VectorList],
                    elide: bool = False) -> List[VectorList]:
@@ -217,7 +212,7 @@ class WorkerRuntime:
                 continue
             block = encode_agg_map(split[dst])
             if block is not None:
-                self.stats.shuffle_bytes += block.nbytes
+                count_shuffle(self.stats, block.nbytes)
             self.tr.send(dst, tag, block)
         parts = []
         for src in range(self.P):
@@ -254,6 +249,19 @@ class WorkerRuntime:
         cand_s = [np.asarray(vl["score"]) for src in gathered for vl in src]
         cand_p = [np.asarray(vl["payload"]) for src in gathered for vl in src]
         merged = merge_topk(op, cand_s, cand_p)
+        return [merged] if merged is not None else []
+
+    def _order(self, op: TCAPOp, i: int,
+               batches: List[VectorList]) -> List[VectorList]:
+        col = op.apply_cols[0]
+        sel = order_partition(op, batches)
+        local = [VectorList({col: sel})] if sel is not None else []
+        gathered = gather_to(self.tr, self.P, f"{i}:sort", 0, local,
+                             self.stats)
+        if gathered is None:  # not the merge root
+            return []
+        merged = merge_ordered(op, [np.asarray(vl[col]) for src in gathered
+                                    for vl in src])
         return [merged] if merged is not None else []
 
     def _output(self, op: TCAPOp, i: int, batches: List[VectorList]) -> None:

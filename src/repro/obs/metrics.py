@@ -22,6 +22,11 @@ kernel_cache.evictions    ctr    exprc kernel LRU
 rows.scanned.total        ctr    Session from per-query ExecStats
 rows.output.total         ctr    Session from per-query ExecStats
 shuffle.bytes.total       ctr    Session from per-query ExecStats
+exchange.shuffle.bytes.total
+                          ctr    each exchange as it moves bytes
+                                 (``relops.count_shuffle``: join shuffles
+                                 and broadcasts, AGG partials, gathers), in
+                                 the process that moves them
 exchanges.elided.total    ctr    Session from per-query ExecStats
 device.h2d.bytes.total    ctr    jax backend at each upload, padded: the
                                  fused-stage core's arguments and the

@@ -124,3 +124,17 @@ def test_scatter_segment_reducer_compiles_for_v5e(one_chip, q1_programs):
     outs = jax.tree.leaves(lowered.out_info)
     assert [str(o.dtype) for o in outs] == list(acc_dtypes)
     assert all(o.shape == (segs,) for o in outs)
+
+
+def test_join_fed_segment_reducer_compiles_for_v5e(one_chip):
+    """TPC-H Q3's AGG at SF 1: about 7.5k joined lines and 2.9k groups a
+    partition pad to 8192 rows and 4096 slots, one float64 revenue sum:
+    the dense form."""
+    segs = 4096
+    assert segs <= relops._DENSE_SEGS_MAX
+    kern = relops._segment_kernel(("sum",), [np.dtype(np.float64)], segs)
+    lowered, compiled = _compile_segment_kernel(
+        kern, one_chip, 8192, [("float64", ())])
+    assert "scatter" not in compiled.as_text()
+    (out,) = jax.tree.leaves(lowered.out_info)
+    assert out.shape == (segs,) and out.dtype == np.float64

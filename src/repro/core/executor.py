@@ -301,14 +301,13 @@ class Executor:
                         splits.append(m.split_by_key_hash(self.P))
                 # each final merges its share of every partial, in
                 # partition order
-                finals = [AggMap(spec) for _ in range(self.P)]
-                for p, final in enumerate(finals):
+                finals = []
+                for p in range(self.P):
                     with rec.span("agg:merge", cat="kernel"):
-                        for split in splits:
-                            if split[p].data:
-                                count_shuffle(self.stats,
-                                              split[p].nbytes())
-                                final.merge(split[p])
+                        shares = [split[p] for split in splits if split[p]]
+                        for share in shares:
+                            count_shuffle(self.stats, share.nbytes())
+                        finals.append(AggMap.merged(spec, shares))
             sp.set(bytes=self.stats.shuffle_bytes - sb0)
         out: List[List[VectorList]] = [[] for _ in range(self.P)]
         for p, m in enumerate(finals):

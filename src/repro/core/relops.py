@@ -20,7 +20,9 @@ Kernels:
   simulated and the real exchange);
 * :func:`probe_join` — sort-probe equi-join of two co-partitioned sides;
 * :class:`AggMap` — PC's pre-aggregation map (a "combiner page"),
-  generalized to multi-column keys and named multi-aggregate accumulators
+  columnar (key and accumulator arrays, folded, split, merged and
+  emitted with vectorised numpy), generalized to multi-column keys and
+  named multi-aggregate accumulators
   (:class:`AggSpec` parses the AGG op's plan); on the jax expression
   backend the per-batch reduction runs on device through
   :func:`device_segment_reducer` (one fused segment-reduce kernel);
@@ -42,7 +44,7 @@ import contextlib
 import struct
 import threading
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -138,7 +140,7 @@ def hash_col(col: np.ndarray) -> np.ndarray:
     """Stable vectorized key hashing (process-independent: shuffle
     routing derived from these values must agree across worker processes
     that share no hash salt)."""
-    if col.dtype.kind in "iu":
+    if col.dtype.kind in "biu":
         x = col.astype(np.int64, copy=True)
         x = (x ^ (x >> 33)) * np.int64(-49064778989728563)  # splitmix64-ish
         return x ^ (x >> 29)
@@ -307,12 +309,9 @@ _COMBINE = {
                                                      np.minimum),
 }
 
-# pairwise merge of two accumulated values (map-merge and wire-merge path)
-_MERGE2 = {
-    "sum": lambda a, b: a + b,
-    "max": np.maximum,
-    "min": np.minimum,
-}
+# the pairwise combine of accumulated values, applied in row order (the
+# map merge, and an absorb into a non-empty map)
+_MERGE_AT = {"sum": np.add.at, "max": np.maximum.at, "min": np.minimum.at}
 
 
 def sum_acc_dtype(dtype: np.dtype) -> np.dtype:
@@ -374,24 +373,29 @@ class AggSpec:
         return op.apply_cols[self.n_keys:]
 
 
-def _col_unique(c: np.ndarray):
-    """``np.unique(..., return_inverse=True)`` with a fast path for byte
-    strings: an ``S1``/``S2``/``S4``/``S8`` column sorts identically as a
-    big-endian unsigned view (lexicographic bytes == big-endian integer
-    order), and integer argsort is ~2x faster than the generic string
-    compare loop. The unique values are viewed back, so callers always
-    see the original dtype."""
+def _sortable(c: np.ndarray) -> np.ndarray:
+    """``c`` as an array that sorts and compares like it, fast: an
+    ``S1``/``S2``/``S4``/``S8`` column as a big-endian unsigned view
+    (lexicographic bytes == big-endian integer order, and integer argsort
+    is ~2x faster than the generic string compare loop); any other
+    column as itself."""
     if c.dtype.kind == "S" and c.dtype.itemsize in (1, 2, 4, 8):
-        u, inv = np.unique(c.view(f">u{c.dtype.itemsize}"),
-                           return_inverse=True)
-        return u.view(c.dtype), inv
-    return np.unique(c, return_inverse=True)
+        return c.view(f">u{c.dtype.itemsize}")
+    return c
+
+
+def _col_unique(c: np.ndarray):
+    """``np.unique(..., return_inverse=True)`` over :func:`_sortable`'s
+    view. The unique values are viewed back, so callers always see the
+    original dtype."""
+    u, inv = np.unique(_sortable(c), return_inverse=True)
+    return u.view(c.dtype), inv
 
 
 def _unique_keys(key_cols: Sequence[np.ndarray]):
-    """(python key list, inverse index) for one partition's rows. Single
-    keys stay scalars (hash/dict identity as before); multi-column keys
-    become tuples. Multi-key grouping runs per-column integer coding — one
+    """(unique key columns, inverse index) for one partition's rows: one
+    array per key column in the source dtype, the groups in lexicographic
+    key order. Multi-key grouping runs per-column integer coding — one
     cheap ``np.unique`` per column, combined into one int64 code — which
     is ~4x faster than a structured-array sort and yields the identical
     lexicographic group order (the combined code sorts by (code0, code1,
@@ -400,7 +404,7 @@ def _unique_keys(key_cols: Sequence[np.ndarray]):
     to the structured sort when the code space could overflow int64."""
     if len(key_cols) == 1:
         uniq, inv = _col_unique(np.asarray(key_cols[0]))
-        return uniq.tolist(), inv
+        return [uniq], inv
     cols = [np.asarray(c) for c in key_cols]
     uniqs, codes, space = [], [], 1
     if all(c.ndim == 1 for c in cols):
@@ -421,37 +425,80 @@ def _unique_keys(key_cols: Sequence[np.ndarray]):
             idx = idx // len(u)
         parts.append(idx)
         parts.reverse()
-        keys = list(zip(*(u[i].tolist() for u, i in zip(uniqs, parts))))
-        return keys, inv
+        return [u[i] for u, i in zip(uniqs, parts)], inv
     packed = np.empty(len(cols[0]), dtype=np.dtype(
         [(f"k{i}", c.dtype, c.shape[1:]) for i, c in enumerate(cols)]))
     for i, c in enumerate(cols):
         packed[f"k{i}"] = c
     uniq, inv = np.unique(packed, return_inverse=True)
-    return uniq.tolist(), inv
+    return [uniq[f"k{i}"].copy() for i in range(len(cols))], inv
+
+
+def _first_occurrence(key_cols: Sequence[np.ndarray]):
+    """``(first, inv)`` for rows grouped by equal key tuples, the groups
+    in the order of their first row: ``first[g]`` is group *g*'s first
+    row (ascending), ``inv`` maps every row to its group. This is a
+    dict's insertion order over the rows, and the first row's key is the
+    one a dict keeps (``-0.0`` and ``0.0`` are one group)."""
+    c = np.asarray(key_cols[0])
+    code = (_sortable(c) if len(key_cols) == 1 and c.ndim == 1
+            else _unique_keys(key_cols)[1])
+    # return_index sorts stably: each group's index is its first row
+    _, first, inv = np.unique(code, return_index=True, return_inverse=True)
+    order = np.argsort(first)
+    rank = np.empty_like(order)
+    rank[order] = np.arange(len(order))
+    return first[order], rank[inv]
+
+
+def _key_hash(key_cols: Sequence[np.ndarray]) -> np.ndarray:
+    """``stable_key_hash`` of every row's key, bit-identical: one key
+    column hashes as :func:`hash_col`; several fold their ``hash_col``
+    values with FNV-1a in wrapping uint64 arithmetic, as
+    ``stable_key_hash`` folds a tuple. Columns ``hash_col`` hashes one
+    element at a time (object, unicode, ...) count
+    ``agg.split.scalar_hash.total``."""
+    hashes = []
+    for c in key_cols:
+        if c.dtype.kind not in "biufS":
+            METRICS.inc("agg.split.scalar_hash.total")
+        hashes.append(hash_col(c))
+    if len(hashes) == 1:
+        return hashes[0]
+    h = np.full(len(hashes[0]), _FNV_OFFSET, dtype=np.uint64)
+    prime = np.uint64(_FNV_PRIME)
+    for x in hashes:
+        h = (h ^ x.view(np.uint64)) * prime
+    return h.view(np.int64)
+
+
+def _writable(a: np.ndarray) -> np.ndarray:
+    return a if a.flags.writeable and a.flags.c_contiguous else a.copy()
 
 
 class AggMap:
     """A pre-aggregation map (the per-thread PC ``Map`` on a combiner page),
     generalized to multi-column keys and multiple named accumulators.
 
-    Each entry maps a key (scalar, or tuple for multi-key grouping) to the
-    list of accumulated values — one per accumulator column of the AGG op.
-    Key order is insertion order everywhere (absorb batches in batch order,
-    merge peers in rank order) — both executors preserve it, which is what
-    keeps final AGG output ordering identical across backends.
+    Columnar: ``keys`` holds one array per key column in the source dtype,
+    ``accs`` one ``(n_groups, ...)`` array per accumulator column of the
+    AGG op, row *g* of each being group *g*. Group order is insertion
+    order everywhere (absorb batches in batch order, merge peers in rank
+    order) — both executors preserve it, which is what keeps final AGG
+    output ordering identical across backends.
     """
 
-    def __init__(self, spec: AggSpec):
+    def __init__(self, spec: AggSpec,
+                 keys: Sequence[np.ndarray] = (),
+                 accs: Sequence[np.ndarray] = ()):
         self.spec = spec
-        self.data: Dict[Any, List[Any]] = {}
-        # source dtypes of the key columns, captured at first absorb and
-        # propagated through splits/merges/the wire: emit() must restore
-        # them exactly (np.array over python natives would widen i32 keys
-        # to int64 and narrow S(n) keys to the longest seen value,
-        # contradicting the synthesized group schema)
-        self.key_dtypes: Optional[List[np.dtype]] = None
+        self.keys: List[np.ndarray] = list(keys)
+        self.accs: List[np.ndarray] = list(accs)
         self._keys_span = None
+
+    def __len__(self) -> int:
+        """The number of groups."""
+        return len(self.keys[0]) if self.keys else 0
 
     def absorb(self, key_cols: Sequence[np.ndarray],
                val_cols: Sequence[np.ndarray],
@@ -469,10 +516,8 @@ class AggMap:
         with keys_span or rec.span("agg:keys", cat="kernel"):
             if len(np.asarray(key_cols[0])) == 0:
                 return
-            if self.key_dtypes is None:
-                self.key_dtypes = [np.asarray(c).dtype for c in key_cols]
             keys, inv = _unique_keys(key_cols)
-        n = len(keys)
+        n = len(keys[0])
         vals = [np.asarray(v) for v in val_cols]
         # the device reducer records its own agg:put and agg:wait spans
         accs = reducer(inv, n, vals) if reducer is not None else None
@@ -480,15 +525,10 @@ class AggMap:
             with rec.span("agg:scatter", cat="kernel"):
                 accs = [_COMBINE[comb](None, inv, v, n)
                         for comb, v in zip(self.spec.combiners, vals)]
-        combs = self.spec.combiners
         with rec.span("agg:fold", cat="kernel"):
-            for i, k in enumerate(keys):
-                cur = self.data.get(k)
-                if cur is None:
-                    self.data[k] = [a[i] for a in accs]
-                else:
-                    self.data[k] = [_MERGE2[c](old, a[i])
-                                    for c, old, a in zip(combs, cur, accs)]
+            part = AggMap(self.spec, keys, accs)
+            folded = AggMap.merged(self.spec, [self, part])
+            self.keys, self.accs = folded.keys, folded.accs
 
     def absorb_batches(self, batches: Sequence[VectorList],
                        key_cols: Sequence[str],
@@ -510,60 +550,68 @@ class AggMap:
             self._keys_span = stack.pop_all()
         self.absorb(keys, vals, reducer=reducer)
 
-    def merge(self, other: "AggMap") -> None:
-        if self.key_dtypes is None:
-            self.key_dtypes = other.key_dtypes
-        combs = self.spec.combiners
-        for k, vals in other.data.items():
-            cur = self.data.get(k)
-            if cur is None:
-                self.data[k] = vals
-            else:
-                self.data[k] = [_MERGE2[c](old, v)
-                                for c, old, v in zip(combs, cur, vals)]
+    @classmethod
+    def merged(cls, spec: AggSpec, parts: Sequence["AggMap"]) -> "AggMap":
+        """The maps ``parts`` merged in list order: the groups in order of
+        first occurrence over the parts' groups; each accumulator starts
+        from its first occurrence's value (so a lone ``-0.0`` passes
+        through unchanged) and folds the later ones in pairwise, in order
+        — bit-identical to folding the parts into a dict entry by entry."""
+        parts = [m for m in parts if m]
+        if not parts:
+            return cls(spec)
+        if len(parts) == 1:
+            return cls(spec, parts[0].keys, parts[0].accs)
+        keys = [np.concatenate(c) for c in zip(*(m.keys for m in parts))]
+        first, inv = _first_occurrence(keys)
+        rest = np.ones(len(inv), bool)
+        rest[first] = False
+        accs = []
+        for comb, cols in zip(spec.combiners, zip(*(m.accs for m in parts))):
+            vals = np.concatenate(cols)
+            acc = vals[first]
+            _MERGE_AT[comb](acc, inv[rest], vals[rest])
+            accs.append(acc)
+        return cls(spec, [k[first] for k in keys], accs)
 
     def split_by_key_hash(self, P: int) -> List["AggMap"]:
-        """Partition this map's entries by ``stable_key_hash(key) % P``
+        """Partition this map's groups by ``stable_key_hash(key) % P``
         (the AGG shuffle kernel — process-independent, so connect-mode
-        workers with different hash salts route each key identically);
-        insertion order is preserved within each split."""
-        out = [AggMap(self.spec) for _ in range(P)]
-        for m in out:
-            m.key_dtypes = self.key_dtypes
-        for k, v in self.data.items():
-            out[stable_key_hash(k) % P].data[k] = v
+        workers with different hash salts route each key identically),
+        computed for all keys at once by :func:`_key_hash`; group order
+        is preserved within each split."""
+        if not self:
+            return [AggMap(self.spec) for _ in range(P)]
+        dest = _key_hash(self.keys) % P
+        order = np.argsort(dest, kind="stable")
+        bounds = np.searchsorted(dest[order], np.arange(P + 1))
+        out = []
+        for lo, hi in zip(bounds[:-1], bounds[1:]):
+            idx = order[lo:hi]
+            out.append(AggMap(self.spec, [k[idx] for k in self.keys],
+                              [a[idx] for a in self.accs]))
         return out
 
     def nbytes(self) -> int:
         """Accumulator payload size (what an AGG partial puts on the wire
         in the local simulation's accounting)."""
-        return sum(np.asarray(v).nbytes
-                   for vals in self.data.values() for v in vals)
+        return sum(a.nbytes for a in self.accs)
 
     def emit(self) -> Optional[VectorList]:
         """The final AGG output batch for this partition (``None`` if the
         partition holds no groups): key columns, then every named output
-        finalized from its accumulator(s)."""
-        if not self.data:
+        finalized from its accumulator(s); every column writable."""
+        if not self:
             return None
-        keys = list(self.data.keys())
         out = VectorList()
-        dts = self.key_dtypes or [None] * self.spec.n_keys
-        if self.spec.n_keys == 1:
-            out.append(self.spec.key_names[0], np.array(keys, dtype=dts[0]))
-        else:
-            for i, kn in enumerate(self.spec.key_names):
-                out.append(kn, np.array([k[i] for k in keys],
-                                        dtype=dts[i]))
-        accs = [np.stack([np.asarray(vals[j]) for vals in
-                          self.data.values()])
-                for j in range(len(self.spec.combiners))]
+        for name, k in zip(self.spec.key_names, self.keys):
+            out.append(name, _writable(k))
         for name, fin in zip(self.spec.out_names, self.spec.finalize):
             if "/" in fin:
                 i, j = map(int, fin.split("/"))
-                out.append(name, accs[i] / accs[j])
+                out.append(name, self.accs[i] / self.accs[j])
             else:
-                out.append(name, accs[int(fin)])
+                out.append(name, _writable(self.accs[int(fin)]))
         return out
 
 

@@ -154,41 +154,20 @@ def encode_agg_map(m: AggMap) -> Optional["PageBlock | PickleBlock"]:
     ``__a<j>`` (``None`` when empty — empty partials never hit the wire).
     Accumulators cross the wire, never finalized outputs, so composite
     aggregates (mean) merge exactly at the receiver."""
-    if not m.data:
+    if not m:
         return None
-    keys = list(m.data.keys())
-    cols: Dict[str, np.ndarray] = {}
-    dts = m.key_dtypes or [None] * m.spec.n_keys
-    if m.spec.n_keys == 1:
-        cols["__k0"] = np.array(keys, dtype=dts[0])
-    else:
-        for i in range(m.spec.n_keys):
-            cols[f"__k{i}"] = np.array([k[i] for k in keys], dtype=dts[i])
-    for j in range(len(m.spec.combiners)):
-        cols[f"__a{j}"] = np.stack(
-            [np.asarray(vals[j]) for vals in m.data.values()])
+    cols = {f"__k{i}": k for i, k in enumerate(m.keys)}
+    cols.update((f"__a{j}", a) for j, a in enumerate(m.accs))
     return encode_batch(VectorList(cols))
 
 
 def decode_agg_map(block, spec: AggSpec) -> AggMap:
+    # the page block's dtype descr keeps the source key dtypes
     vl = decode_batch(block)
-    m = AggMap(spec)
-    # the page block's dtype descr preserved the source key dtypes — hand
-    # them back to the map so its final emit restores them exactly
-    m.key_dtypes = [np.asarray(vl[f"__k{i}"]).dtype
-                    for i in range(spec.n_keys)]
-    accs = [np.asarray(vl[f"__a{j}"])
-            for j in range(len(spec.combiners))]
-    # .tolist() restores native python keys so hashing and dict identity
-    # match the sender's map exactly
-    if spec.n_keys == 1:
-        keys = np.asarray(vl["__k0"]).tolist()
-    else:
-        keys = list(zip(*(np.asarray(vl[f"__k{i}"]).tolist()
-                          for i in range(spec.n_keys))))
-    for i, k in enumerate(keys):
-        m.data[k] = [a[i] for a in accs]
-    return m
+    return AggMap(spec,
+                  [np.asarray(vl[f"__k{i}"]) for i in range(spec.n_keys)],
+                  [np.asarray(vl[f"__a{j}"])
+                   for j in range(len(spec.combiners))])
 
 
 # ----------------------------------------------------------- TCP framing

@@ -222,11 +222,8 @@ class WorkerRuntime:
                 block = self.tr.recv(src, tag)
                 if block is not None:
                     parts.append(decode_agg_map(block, spec))
-        final = AggMap(spec)
         with rec.span("agg:merge", cat="kernel"):
-            for part in parts:
-                if part.data:
-                    final.merge(part)
+            final = AggMap.merged(spec, parts)
         with rec.span("agg:emit", cat="kernel"):
             emitted = final.emit()
         return [emitted] if emitted is not None else []

@@ -1,24 +1,30 @@
 """PC's vectorized execution engine (paper §5.2, Appendix C/D), host side.
 
-Pipelines push *vector lists* (column batches) through compiled stages. The
-distributed semantics are simulated with P logical partitions on one host:
+Pipelines push *vector lists* (column batches) through compiled stages.
+:class:`Executor` is the one op layer of every backend: one dispatch loop
+and one implementation of each op, run over the partitions this process
+holds (all P of them here, one per rank on a distributed worker):
 
 * **JOIN** — broadcast (build side replicated) or hash-partition (both sides
   shuffled by key hash) per the physical planner's decision, then build+probe;
 * **AGG** — PC's two-stage plan: per-partition *pre-aggregation* into maps
   ("combiner pages"), shuffle partials by key hash, final aggregation;
-* **TOPK** — per-partition top-k, then a global merge (the paper's
-  TopJaccard pattern);
+* **TOPK** — per-partition top-k, then a global merge at partition 0 (the
+  paper's TopJaccard pattern);
 * **SORT** — ``ORDER BY ... LIMIT``: per-partition pre-selection, then a
-  global gather-merge.
+  global gather-merge at partition 0.
 
-The per-partition operator kernels live in :mod:`repro.core.relops` and are
-shared verbatim with the distributed worker runtime (:mod:`repro.dist`);
-this module only decides partition *placement* (greedy least-loaded pages,
-shared with ``dist.placement``) and
-simulates the *exchange* in-process. The real exchange — page-serialized
-transfers between workers — is :class:`repro.dist.driver
-.DistributedExecutor`, which runs the same kernels.
+The ops reach other partitions only through an *exchange* with four
+operations: a join side's hash shuffle, a build side's broadcast, the AGG
+partials' split/move/merge, and a gather to partition 0. Two exchanges
+exist, and they are all that differs between the backends:
+:class:`LocalExchange` here moves buckets in memory between the P
+partitions simulated in one process; the distributed worker runtime
+(:class:`repro.dist.worker.WorkerRuntime`, a subclass that overrides only
+the scan of its shard and the OUTPUT gather) binds
+:class:`repro.dist.exchange.TransportExchange`, which moves page blocks
+between ranks. The per-partition kernels live in :mod:`repro.core.relops`;
+placement is greedy least-loaded pages, shared with ``dist.placement``.
 
 A row-at-a-time *volcano* interpreter (:class:`NaiveExecutor`) implements
 identical semantics one record at a time — the execution model the paper
@@ -38,18 +44,17 @@ from repro.core.exprc import EXPR_BACKENDS, FusedStage, build_steps
 from repro.core.optimizer import OptimizerReport, optimize
 from repro.core.physical import PhysicalPlan, plan_physical
 from repro.core.relops import (AggMap, AggSpec, assemble_output,
-                               batch_kernel, batch_topk, bytes_of,
-                               concat_batches, count_shuffle,
-                               device_segment_reducer,
+                               batch_kernel, batch_topk, bucket_by_hash,
+                               bytes_of, concat_batches, count_shuffle,
+                               device_segment_reducer, exchange_span,
                                greedy_page_placement, merge_ordered,
-                               merge_topk, order_partition, probe_join,
-                               split_by_hash)
+                               merge_topk, order_partition, probe_join)
 from repro.core.tcap import TCAPOp, TCAPProgram
 from repro.obs.trace import NULL, current, op_name, using
 from repro.objectmodel.store import PagedStore
 from repro.objectmodel.vectorlist import VectorList
 
-__all__ = ["Executor", "NaiveExecutor", "ExecStats"]
+__all__ = ["Executor", "NaiveExecutor", "ExecStats", "LocalExchange"]
 
 
 @dataclasses.dataclass
@@ -71,11 +76,69 @@ def _part_rows(parts) -> int:
     return sum(vl.num_rows or 0 for batches in parts for vl in batches)
 
 
+class LocalExchange:
+    """The exchange between the P partitions of one process: rows move as
+    buckets in memory. Each operation takes all P partitions and returns
+    one result per partition. Its byte accounting: a join shuffle counts
+    the rows that change partition, a broadcast P-1 copies of the build
+    side's raw column bytes, the AGG exchange every share, the
+    partition's own included."""
+
+    def shuffle(self, parts, hash_name: str, tag: str,
+                stats: ExecStats) -> List[VectorList]:
+        """Repartition batches by ``hash % P`` (the network shuffle)."""
+        P = len(parts)
+        with exchange_span("shuffle", tag, stats):
+            buckets: List[List[VectorList]] = [[] for _ in range(P)]
+            for pi, batches in enumerate(parts):
+                for p, subs in enumerate(
+                        bucket_by_hash(batches, hash_name, P)):
+                    if p != pi:
+                        for sub in subs:
+                            count_shuffle(stats, bytes_of(sub))
+                    buckets[p] += subs
+            return [concat_batches(b) for b in buckets]
+
+    def broadcast(self, parts, tag: str,
+                  stats: ExecStats) -> List[VectorList]:
+        """The whole build side, for every partition."""
+        with exchange_span("bcast", tag, stats):
+            build = concat_batches([vl for bl in parts for vl in bl])
+            count_shuffle(stats, bytes_of(build) * max(0, len(parts) - 1))
+        return [build] * len(parts)
+
+    def merge_partials(self, spec: AggSpec, partials: List[AggMap],
+                       tag: str, stats: ExecStats) -> List[AggMap]:
+        """Split every partial by key hash; each final merges its share of
+        every partial, in partition order."""
+        P = len(partials)
+        rec = current()
+        with exchange_span("shuffle", tag, stats):
+            splits = []
+            for m in partials:
+                with rec.span("agg:split", cat="kernel"):
+                    splits.append(m.split_by_key_hash(P))
+            finals = []
+            for p in range(P):
+                with rec.span("agg:merge", cat="kernel"):
+                    shares = [split[p] for split in splits if split[p]]
+                    for share in shares:
+                        count_shuffle(stats, share.nbytes())
+                    finals.append(AggMap.merged(spec, shares))
+        return finals
+
+    def gather(self, parts, tag: str, stats: ExecStats):
+        """Every partition's batches, at partition 0: nothing moves."""
+        return parts
+
+
 class Executor:
     """Vectorized TCAP executor over a PagedStore with P logical partitions."""
 
     #: stage-compiler backend; NaiveExecutor pins "interp" (see below)
     expr_backend = "numpy"
+    #: how rows reach other partitions (a worker rank binds its transport)
+    exchange = LocalExchange()
 
     def __init__(self, store: PagedStore, num_partitions: int = 4,
                  vector_rows: int = 8192, do_optimize: bool = True,
@@ -131,8 +194,7 @@ class Executor:
         data: Dict[str, List[List[VectorList]]] = {}
         result: Dict[str, np.ndarray] = {}
 
-        # the op index within the program: exchange tags key on it, and the
-        # per-op span names must match the worker runtime's exactly (fused
+        # the op index within the program: exchange tags key on it (fused
         # steps advance it by their op count)
         i = -1
         with using(rec):
@@ -166,11 +228,11 @@ class Executor:
                             op, i, data[op.in_list],
                             elide=id(op) in plan.agg_elide)
                     elif op.op == "TOPK":
-                        data[op.out] = self._topk(op, data[op.in_list])
+                        data[op.out] = self._topk(op, i, data[op.in_list])
                     elif op.op == "SORT":
-                        data[op.out] = self._order(op, data[op.in_list])
+                        data[op.out] = self._order(op, i, data[op.in_list])
                     elif op.op == "OUTPUT":
-                        result = self._output(op, data[op.in_list])
+                        result = self._output(op, i, data[op.in_list])
                     else:
                         raise ValueError(f"unknown op {op.op}")
                 if rec.enabled:
@@ -211,57 +273,33 @@ class Executor:
         already hash-partitioned on their join key (PL202): those concat
         in place — byte-identical to shuffling, since the shuffle of a
         correctly-placed side is the identity permutation — and count as
-        elided exchanges instead of shuffle bytes."""
+        elided exchanges instead of shuffle bytes. Every rank takes the
+        same branch (the plan ships to the workers), so none waits on a
+        peer that skipped the exchange."""
+        stats = self.stats
         if algo == "broadcast":
-            self.stats.broadcast_joins += 1
-            sb0 = self.stats.shuffle_bytes
-            with current().span(f"x:bcast:{i}:build", cat="exchange",
-                                tag=f"{i}:build") as sp:
-                build_all = concat_batches([vl for bl in right for vl in bl])
-                count_shuffle(self.stats,
-                              bytes_of(build_all) * max(0, self.P - 1))
-            sp.set(bytes=self.stats.shuffle_bytes - sb0)
-            rparts = [build_all] * self.P
+            stats.broadcast_joins += 1
+            rparts = self.exchange.broadcast(right, f"{i}:build", stats)
             lparts = [concat_batches(p) for p in left]
         else:
-            self.stats.hash_partition_joins += 1
-            if "L" in elide:
-                self.stats.exchanges_elided += 1
-                lparts = [concat_batches(p) for p in left]
-            else:
-                lparts = self._shuffle(left, op.apply_cols[0], f"{i}:L")
-            if "R" in elide:
-                self.stats.exchanges_elided += 1
-                rparts = [concat_batches(p) for p in right]
-            else:
-                rparts = self._shuffle(right, op.apply_cols2[0], f"{i}:R")
-        out: List[List[VectorList]] = [[] for _ in range(self.P)]
-        for p in range(self.P):
-            probed = probe_join(op, lparts[p], rparts[p])
-            if probed is None:
-                continue
-            res, n = probed
-            self.stats.rows_joined += n
-            out[p].append(res)
-        return out
-
-    def _shuffle(self, parts, hash_name: str, tag: str) -> List[VectorList]:
-        """Repartition batches by hash % P (the network shuffle)."""
-        sb0 = self.stats.shuffle_bytes
-        with current().span(f"x:shuffle:{tag}", cat="exchange",
-                            tag=tag) as sp:
-            buckets: List[List[VectorList]] = [[] for _ in range(self.P)]
-            for pi, batches in enumerate(parts):
-                for vl in batches:
-                    for p, sub in enumerate(
-                            split_by_hash(vl, hash_name, self.P)):
-                        if sub is None:
-                            continue
-                        if p != pi:
-                            count_shuffle(self.stats, bytes_of(sub))
-                        buckets[p].append(sub)
-            out = [concat_batches(b) for b in buckets]
-        sp.set(bytes=self.stats.shuffle_bytes - sb0)
+            stats.hash_partition_joins += 1
+            sides = []
+            for side, parts, col in (("L", left, op.apply_cols[0]),
+                                     ("R", right, op.apply_cols2[0])):
+                if side in elide:
+                    stats.exchanges_elided += 1
+                    sides.append([concat_batches(p) for p in parts])
+                else:
+                    sides.append(self.exchange.shuffle(
+                        parts, col, f"{i}:{side}", stats))
+            lparts, rparts = sides
+        out: List[List[VectorList]] = [[] for _ in lparts]
+        for p, (lvl, rvl) in enumerate(zip(lparts, rparts)):
+            probed = probe_join(op, lvl, rvl)
+            if probed is not None:
+                res, n = probed
+                stats.rows_joined += n
+                out[p].append(res)
         return out
 
     # -------------------------------------------------------------- agg
@@ -274,9 +312,8 @@ class Executor:
         reducer = (device_segment_reducer(spec.combiners)
                    if self.expr_backend == "jax" else None)
         # stage 1: per-partition pre-aggregation (combiner pages), one
-        # absorb over the partition's concatenated rows (AggMap
-        # .absorb_batches — shared with the worker runtime, which is what
-        # keeps the float association order identical across backends)
+        # absorb over the partition's concatenated rows, which fixes the
+        # float association order on every backend
         partials = []
         for batches in parts:
             m = AggMap(spec)
@@ -287,29 +324,14 @@ class Executor:
         # partitioned on the key tuple, every partial holds only keys
         # routing to itself — the split+merge is the identity permutation,
         # so the partials *are* the finals and no bytes move
-        rec = current()
         if elide:
             self.stats.exchanges_elided += 1
             finals = partials
         else:
-            sb0 = self.stats.shuffle_bytes
-            with rec.span(f"x:shuffle:{i}:partials", cat="exchange",
-                          tag=f"{i}:partials") as sp:
-                splits = []
-                for m in partials:
-                    with rec.span("agg:split", cat="kernel"):
-                        splits.append(m.split_by_key_hash(self.P))
-                # each final merges its share of every partial, in
-                # partition order
-                finals = []
-                for p in range(self.P):
-                    with rec.span("agg:merge", cat="kernel"):
-                        shares = [split[p] for split in splits if split[p]]
-                        for share in shares:
-                            count_shuffle(self.stats, share.nbytes())
-                        finals.append(AggMap.merged(spec, shares))
-            sp.set(bytes=self.stats.shuffle_bytes - sb0)
-        out: List[List[VectorList]] = [[] for _ in range(self.P)]
+            finals = self.exchange.merge_partials(
+                spec, partials, f"{i}:partials", self.stats)
+        rec = current()
+        out: List[List[VectorList]] = [[] for _ in finals]
         for p, m in enumerate(finals):
             with rec.span("agg:emit", cat="kernel"):
                 emitted = m.emit()
@@ -317,30 +339,43 @@ class Executor:
                 out[p].append(emitted)
         return out
 
-    def _topk(self, op: TCAPOp, parts) -> List[List[VectorList]]:
-        best_s: List[np.ndarray] = []
-        best_p: List[np.ndarray] = []
+    # ---------------------------------------------------- top-k and sort
+    def _topk(self, op: TCAPOp, i: int, parts) -> List[List[VectorList]]:
+        local = []
         for batches in parts:  # per-partition top-k, then merge
-            for vl in batches:
-                s, pay = batch_topk(op, vl)
-                best_s.append(s)
-                best_p.append(pay)
-        out: List[List[VectorList]] = [[] for _ in range(self.P)]
-        merged = merge_topk(op, best_s, best_p)
-        if merged is not None:
-            out[0].append(merged)
+            best = [batch_topk(op, vl) for vl in batches]
+            local.append([VectorList(
+                {"score": np.concatenate([s for s, _ in best]),
+                 "payload": np.concatenate([p for _, p in best])})]
+                if best else [])
+        return self._merge_at_root(
+            parts, local, f"{i}:topk", lambda cands: merge_topk(
+                op, [np.asarray(vl["score"]) for vl in cands],
+                [np.asarray(vl["payload"]) for vl in cands]))
+
+    def _order(self, op: TCAPOp, i: int, parts) -> List[List[VectorList]]:
+        col = op.apply_cols[0]
+        local = []
+        for batches in parts:
+            sel = order_partition(op, batches)
+            local.append([VectorList({col: sel})] if sel is not None else [])
+        return self._merge_at_root(
+            parts, local, f"{i}:sort", lambda cands: merge_ordered(
+                op, [np.asarray(vl[col]) for vl in cands]))
+
+    def _merge_at_root(self, parts, local, tag: str, merge
+                       ) -> List[List[VectorList]]:
+        """Gather every partition's candidates to partition 0 and ``merge``
+        them there, in partition-then-batch order."""
+        gathered = self.exchange.gather(local, tag, self.stats)
+        out: List[List[VectorList]] = [[] for _ in parts]
+        if gathered is not None:
+            merged = merge([vl for src in gathered for vl in src])
+            if merged is not None:
+                out[0].append(merged)
         return out
 
-    def _order(self, op: TCAPOp, parts) -> List[List[VectorList]]:
-        cands = [c for c in (order_partition(op, b) for b in parts)
-                 if c is not None]
-        out: List[List[VectorList]] = [[] for _ in range(self.P)]
-        merged = merge_ordered(op, cands)
-        if merged is not None:
-            out[0].append(merged)
-        return out
-
-    def _output(self, op: TCAPOp, parts) -> Dict[str, np.ndarray]:
+    def _output(self, op: TCAPOp, i: int, parts) -> Dict[str, np.ndarray]:
         return assemble_output(
             op, [vl for batches in parts for vl in batches],
             self.stats, self.store, self.write_outputs)

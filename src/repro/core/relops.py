@@ -2,11 +2,11 @@
 
 Everything here operates on ONE partition's data — a vector list or a list
 of vector-list batches — with no knowledge of where partitions live or how
-they exchange data. The local simulated :class:`~repro.core.executor
-.Executor` and the distributed :class:`~repro.dist.driver
-.DistributedExecutor` both call these kernels, so the two backends differ
-only in partition *placement* and *exchange*, never in operator semantics.
-That is what makes byte-identical results across backends a structural
+they exchange data. The one op layer (:class:`~repro.core.executor
+.Executor`, which the distributed worker runtime subclasses) calls these
+kernels on every backend, so the backends differ only in partition
+*placement* and *exchange*, never in operator semantics. That is what
+makes byte-identical results across backends a structural
 property rather than a testing accident.
 
 Kernels:
@@ -15,9 +15,10 @@ Kernels:
   (APPLY / FILTER / FLATTEN / HASH) over one vector-list batch;
 * :func:`hash_col` — stable vectorized key hashing (drives both the HASH
   op and shuffle destinations);
-* :func:`split_by_hash` — partition one batch by ``hash % P`` (the shuffle
-  kernel: what goes on the wire is decided here, identically for the
-  simulated and the real exchange);
+* :func:`split_by_hash` / :func:`bucket_by_hash` — partition one batch,
+  or one partition's batches, by ``hash % P`` (the shuffle kernel: what
+  goes on the wire is decided here, identically for the in-memory and
+  the transport exchange);
 * :func:`probe_join` — sort-probe equi-join of two co-partitioned sides;
 * :class:`AggMap` — PC's pre-aggregation map (a "combiner page"),
   columnar (key and accumulator arrays, folded, split, merged and
@@ -33,7 +34,8 @@ Kernels:
 * :func:`order_partition` / :func:`merge_ordered` — ``ORDER BY ... LIMIT``:
   per-partition pre-selection and the gather-merge, both in the total
   order :func:`order_select` fixes;
-* :func:`count_shuffle` — every exchange's byte accounting;
+* :func:`count_shuffle` / :func:`exchange_span` — every exchange's byte
+  accounting and span;
 * :func:`assemble_output` — the OUTPUT contract (column concat in
   partition-then-batch order, row count, single-column write-back);
 * :func:`concat_batches` / :func:`bytes_of` — glue.
@@ -56,11 +58,11 @@ from repro.objectmodel.vectorlist import VectorList
 
 __all__ = [
     "AggMap", "AggSpec", "SHUFFLE_COUNTER", "assemble_output",
-    "batch_kernel", "batch_topk", "bytes_of", "concat_batches",
-    "count_shuffle", "device_segment_reducer", "greedy_page_placement",
-    "hash_col", "merge_ordered", "merge_topk", "order_partition",
-    "order_select", "probe_join", "split_by_hash", "stable_key_hash",
-    "stage_eval",
+    "batch_kernel", "batch_topk", "bucket_by_hash", "bytes_of",
+    "concat_batches", "count_shuffle", "device_segment_reducer",
+    "exchange_span", "greedy_page_placement", "hash_col", "merge_ordered",
+    "merge_topk", "order_partition", "order_select", "probe_join",
+    "split_by_hash", "stable_key_hash", "stage_eval",
 ]
 
 _FNV_OFFSET = 0xcbf29ce484222325
@@ -264,6 +266,19 @@ def split_by_hash(vl: VectorList, hash_name: str, P: int
         mask = dest == p
         out.append(vl.filtered(mask, vl.names) if mask.any() else None)
     return out
+
+
+def bucket_by_hash(batches: Sequence[VectorList], hash_name: str, P: int
+                   ) -> List[List[VectorList]]:
+    """One partition's side of a hash shuffle: per destination, the rows
+    of ``batches`` that route there (:func:`split_by_hash`), in batch
+    order. Both exchanges split through this."""
+    buckets: List[List[VectorList]] = [[] for _ in range(P)]
+    for vl in batches:
+        for p, sub in enumerate(split_by_hash(vl, hash_name, P)):
+            if sub is not None:
+                buckets[p].append(sub)
+    return buckets
 
 
 def probe_join(op: TCAPOp, lvl: VectorList, rvl: VectorList
@@ -484,7 +499,7 @@ class AggMap:
     ``accs`` one ``(n_groups, ...)`` array per accumulator column of the
     AGG op, row *g* of each being group *g*. Group order is insertion
     order everywhere (absorb batches in batch order, merge peers in rank
-    order) — both executors preserve it, which is what keeps final AGG
+    order) — both exchanges preserve it, which is what keeps final AGG
     output ordering identical across backends.
     """
 
@@ -536,9 +551,9 @@ class AggMap:
                        reducer: Optional[Callable] = None) -> None:
         """One absorb over a partition's concatenated rows — a single
         group discovery + one (fused, possibly on-device) scatter per
-        partition. Both executors pre-aggregate through exactly this
-        method, so the float association order (row order within the
-        partition) is identical on every backend by construction."""
+        partition. The op layer pre-aggregates every partition through
+        exactly this method, so the float association order (row order
+        within the partition) is identical on every backend."""
         if not batches:
             return
         with contextlib.ExitStack() as stack:
@@ -856,6 +871,16 @@ def count_shuffle(stats, nbytes: int) -> None:
     :data:`SHUFFLE_COUNTER`."""
     stats.shuffle_bytes += nbytes
     METRICS.inc(SHUFFLE_COUNTER, nbytes)
+
+
+@contextlib.contextmanager
+def exchange_span(kind: str, tag: str, stats):
+    """The span ``x:{kind}:{tag}`` (category ``exchange``) of one exchange,
+    its ``bytes`` the shuffle bytes counted inside it."""
+    sb0 = stats.shuffle_bytes
+    with current().span(f"x:{kind}:{tag}", cat="exchange", tag=tag) as sp:
+        yield
+    sp.set(bytes=stats.shuffle_bytes - sb0)
 
 
 # ----------------------------------------------------------------- output

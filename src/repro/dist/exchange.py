@@ -1,16 +1,21 @@
-"""The exchange layer: transports + the three communication patterns.
+"""The transport exchange: transports, and the exchange a worker rank binds.
 
-Patterns (each operating on page blocks, tagged by TCAP op index so
-concurrent exchanges of one program never interleave):
+The op layer (:class:`~repro.core.executor.Executor`) is the same on every
+backend and reaches other partitions only through an exchange with four
+operations. The local executor binds the in-memory
+:class:`~repro.core.executor.LocalExchange`; a worker rank
+(:class:`~repro.dist.worker.WorkerRuntime`) binds
+:class:`TransportExchange`, which moves page blocks between ranks:
 
-* :func:`exchange_partitions` — hash-partition shuffle (JOIN sides, one
-  call per side): every worker splits its batches by key hash, sends each
-  peer that peer's bucket of sub-batches and keeps its own bucket
-  unserialized (locality is free);
-* :func:`all_gather` — broadcast: every worker replicates its batches to
-  all peers (small-side joins; serialized once, shipped P-1 times);
-* :func:`gather_to` — gather-merge: everyone ships to one root (TOPK's
-  global merge at worker 0, OUTPUT's collect at the driver).
+* ``shuffle`` — hash-partition shuffle of a JOIN side: every rank splits
+  its batches by key hash, sends each peer that peer's bucket of
+  sub-batches and keeps its own bucket unserialized;
+* ``broadcast`` — a JOIN's build side: every rank replicates its batches
+  to all peers (serialized once, shipped P-1 times);
+* ``merge_partials`` — the AGG partials: split by key hash, the peers'
+  shares sent as packed blocks, merged in rank order;
+* ``gather`` — everyone ships to one root (TOPK's and SORT's merge at
+  rank 0, OUTPUT's collect at the driver).
 
 Three transports behind one interface:
 
@@ -25,7 +30,8 @@ Three transports behind one interface:
 All move the same serialized page blocks, so ``shuffle_bytes`` measures
 identical traffic regardless of the worker kind. ``recv`` buffers by
 (source, tag): the exchange schedule is SPMD-deterministic, but message
-*arrival* order is not.
+*arrival* order is not. A ``recv`` raises :class:`PeerAborted` when the
+driver broadcasts ABORT.
 """
 from __future__ import annotations
 
@@ -34,15 +40,16 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.executor import ExecStats
-from repro.core.relops import concat_batches, count_shuffle, split_by_hash
-from repro.dist.protocol import (ABORT, DRIVER, decode_batch, encode_batch,
-                                 read_frame, write_frame)
+from repro.core.relops import (AggMap, AggSpec, bucket_by_hash,
+                               concat_batches, count_shuffle, exchange_span)
+from repro.dist.protocol import (ABORT, DRIVER, decode_agg_map, decode_batch,
+                                 encode_agg_map, encode_batch, read_frame,
+                                 write_frame)
 from repro.obs.trace import current
 from repro.objectmodel.vectorlist import VectorList
 
 __all__ = ["PeerAborted", "ThreadTransport", "ProcessTransport",
-           "SocketTransport", "exchange_partitions", "all_gather",
-           "gather_to"]
+           "SocketTransport", "TransportExchange"]
 
 
 class PeerAborted(RuntimeError):
@@ -148,79 +155,99 @@ class SocketTransport:
             pass
 
 
-# ------------------------------------------------------------- patterns
-def exchange_partitions(tr, P: int, tag: str, batches: List[VectorList],
-                        hash_name: str, stats: ExecStats) -> VectorList:
-    """Hash-partition shuffle of this worker's ``batches`` by the column
-    ``hash_name`` (``hash % P``, :func:`~repro.core.relops.split_by_hash`).
-    Returns the rows that landed here, concatenated in (source rank,
-    batch) order — own bucket stays unserialized. The split, the
-    transfers and the concatenation all sit inside the exchange span, as
-    in the local executor's shuffle."""
-    rank = tr.rank
-    sb0 = stats.shuffle_bytes
-    with current().span(f"x:shuffle:{tag}", cat="exchange", tag=tag) as sp:
-        buckets: List[List[VectorList]] = [[] for _ in range(P)]
-        for vl in batches:
-            for p, sub in enumerate(split_by_hash(vl, hash_name, P)):
-                if sub is not None:
-                    buckets[p].append(sub)
-        for dst in range(P):
+# ------------------------------------------------------------- exchange
+class TransportExchange:
+    """The exchange of one rank among ``P``: the op layer
+    (:class:`~repro.core.executor.Executor`) hands it a one-element list,
+    this rank's partition, and rows cross to the other ranks as encoded
+    page blocks over the transport ``tr``, tagged by TCAP op index so
+    concurrent exchanges of one program never interleave. Every
+    operation returns this rank's one-element result; ``shuffle_bytes``
+    counts the encoded blocks sent to peers (the own share stays
+    unserialized: locality is free)."""
+
+    def __init__(self, tr, P: int):
+        self.tr = tr
+        self.P = P
+
+    def shuffle(self, parts, hash_name: str, tag: str,
+                stats: ExecStats) -> List[VectorList]:
+        """Hash-partition shuffle by the column ``hash_name`` (``hash %
+        P``): the rows that land here, concatenated in (source rank,
+        batch) order. The split, the transfers and the concatenation all
+        sit inside the exchange span."""
+        rank, P = self.tr.rank, self.P
+        with exchange_span("shuffle", tag, stats):
+            buckets = bucket_by_hash(parts[0], hash_name, P)
+            for dst in range(P):
+                if dst == rank:
+                    continue
+                blocks = [encode_batch(vl) for vl in buckets[dst]]
+                count_shuffle(stats, sum(b.nbytes for b in blocks))
+                self.tr.send(dst, tag, blocks)
+            inbox = [buckets[rank] if src == rank else
+                     [decode_batch(b) for b in self.tr.recv(src, tag)]
+                     for src in range(P)]
+            return [concat_batches([vl for src in inbox for vl in src])]
+
+    def broadcast(self, parts, tag: str,
+                  stats: ExecStats) -> List[VectorList]:
+        """Replicate this rank's batches to every peer (serialized once,
+        shipped P-1 times); the build side is every rank's batches in
+        rank order."""
+        rank = self.tr.rank
+        with exchange_span("bcast", tag, stats):
+            blocks = None
+            for dst in range(self.P):
+                if dst == rank:
+                    continue
+                if blocks is None:
+                    blocks = [encode_batch(vl) for vl in parts[0]]
+                count_shuffle(stats, sum(b.nbytes for b in blocks))
+                self.tr.send(dst, tag, blocks)
+            srcs = [parts[0] if src == rank else
+                    [decode_batch(b) for b in self.tr.recv(src, tag)]
+                    for src in range(self.P)]
+        return [concat_batches([vl for src in srcs for vl in src])]
+
+    def merge_partials(self, spec: AggSpec, partials: List[AggMap],
+                       tag: str, stats: ExecStats) -> List[AggMap]:
+        """Split this rank's partial by key hash, send each peer its share
+        as one packed block (accumulators cross the wire, never finalized
+        means) and merge the shares that land here in rank order."""
+        rec, rank = current(), self.tr.rank
+        with rec.span("agg:split", cat="kernel"):
+            split = partials[0].split_by_key_hash(self.P)
+        for dst in range(self.P):
             if dst == rank:
                 continue
-            blocks = [encode_batch(vl) for vl in buckets[dst]]
-            count_shuffle(stats, sum(b.nbytes for b in blocks))
-            tr.send(dst, tag, blocks)
-        inbox: List[List[VectorList]] = []
-        for src in range(P):
+            block = encode_agg_map(split[dst])
+            if block is not None:
+                count_shuffle(stats, block.nbytes)
+            self.tr.send(dst, tag, block)
+        shares = []
+        for src in range(self.P):
             if src == rank:
-                inbox.append(buckets[rank])
+                shares.append(split[rank])
             else:
-                inbox.append([decode_batch(b) for b in tr.recv(src, tag)])
-        out = concat_batches([vl for src in inbox for vl in src])
-    sp.set(bytes=stats.shuffle_bytes - sb0)
-    return out
+                block = self.tr.recv(src, tag)
+                if block is not None:
+                    shares.append(decode_agg_map(block, spec))
+        with rec.span("agg:merge", cat="kernel"):
+            return [AggMap.merged(spec, shares)]
 
-
-def all_gather(tr, P: int, tag: str, batches: List[VectorList],
-               stats: ExecStats) -> List[List[VectorList]]:
-    """Broadcast: replicate this worker's batches to every peer; returns
-    all workers' batches in rank order (serialize once, ship P-1 times)."""
-    rank = tr.rank
-    sb0 = stats.shuffle_bytes
-    with current().span(f"x:bcast:{tag}", cat="exchange", tag=tag) as sp:
-        blocks = None
-        for dst in range(P):
-            if dst == rank:
-                continue
-            if blocks is None:
-                blocks = [encode_batch(vl) for vl in batches]
-            count_shuffle(stats, sum(b.nbytes for b in blocks))
-            tr.send(dst, tag, blocks)
-        out = [batches if src == rank else
-               [decode_batch(b) for b in tr.recv(src, tag)]
-               for src in range(P)]
-    sp.set(bytes=stats.shuffle_bytes - sb0)
-    return out
-
-
-def gather_to(tr, P: int, tag: str, root: int,
-              batches: List[VectorList],
-              stats: ExecStats) -> Optional[List[List[VectorList]]]:
-    """Gather-merge: every worker ships its batches to ``root`` (a worker
-    rank, or :data:`DRIVER`). Returns the per-source batch lists at the
-    root, ``None`` elsewhere."""
-    rank = tr.rank
-    sb0 = stats.shuffle_bytes
-    with current().span(f"x:gather:{tag}", cat="exchange", tag=tag) as sp:
-        if rank != root:
-            blocks = [encode_batch(vl) for vl in batches]
-            count_shuffle(stats, sum(b.nbytes for b in blocks))
-            tr.send(root, tag, blocks)
-            out = None
-        else:
-            out = [batches if src == rank else
-                   [decode_batch(b) for b in tr.recv(src, tag)]
-                   for src in range(P)]
-    sp.set(bytes=stats.shuffle_bytes - sb0)
-    return out
+    def gather(self, parts, tag: str, stats: ExecStats, root: int = 0
+               ) -> Optional[List[List[VectorList]]]:
+        """Gather-merge: every rank ships its batches to ``root`` (a rank,
+        or :data:`DRIVER`). Returns the per-source batch lists at the
+        root, ``None`` elsewhere."""
+        rank = self.tr.rank
+        with exchange_span("gather", tag, stats):
+            if rank != root:
+                blocks = [encode_batch(vl) for vl in parts[0]]
+                count_shuffle(stats, sum(b.nbytes for b in blocks))
+                self.tr.send(root, tag, blocks)
+                return None
+            return [parts[0] if src == rank else
+                    [decode_batch(b) for b in self.tr.recv(src, tag)]
+                    for src in range(self.P)]

@@ -1,24 +1,20 @@
-"""The worker runtime: SPMD execution of one TCAP program over one shard.
+"""The worker runtime: one rank of the op layer, over one shard.
 
-Every worker runs the *same* op sequence (the paper's staged plan), calling
-the same per-partition kernels as the local simulated executor
-(:mod:`repro.core.relops`) over its own :class:`~repro.objectmodel.store
-.PagedStore` shard, and hitting the exchange layer at the ops the physical
-plan stages across workers:
-
-* JOIN — ``all_gather`` of the build side (broadcast) or
-  ``exchange_partitions`` of both sides (hash-partition shuffle);
-* AGG — pre-aggregate locally, ``exchange_partitions`` of the partial maps
-  by key hash, final merge;
-* TOPK — local per-batch top-k, ``gather_to`` worker 0, global merge there;
-* SORT — local pre-selection, ``gather_to`` worker 0, global merge there;
-* OUTPUT — ``gather_to`` the driver.
+Every worker runs the *same* op sequence (the paper's staged plan) through
+the one op layer, :class:`~repro.core.executor.Executor`, over the one
+partition it holds: its own :class:`~repro.objectmodel.store.PagedStore`
+shard. :class:`WorkerRuntime` binds a
+:class:`~repro.dist.exchange.TransportExchange` in place of the local
+executor's in-memory exchange, so at the ops the physical plan stages
+across workers its rows cross to the peers as page blocks: the JOIN
+sides' shuffle or the build side's broadcast, the AGG partials, the
+TOPK and SORT candidates gathered to rank 0. It overrides only the scan
+(of its shard) and OUTPUT (a gather to the driver).
 
 Because placement is the same greedy-by-bytes rule the local executor
-simulates and
-exchanges preserve (source rank, batch) order, results are byte-identical
-to ``Executor`` with ``num_partitions == num_workers`` — enforced by
-``tests/test_dist.py``.
+uses and exchanges preserve (source rank, batch) order, results are
+byte-identical to ``Executor`` with ``num_partitions == num_workers`` —
+enforced by ``tests/test_dist.py``.
 """
 from __future__ import annotations
 
@@ -27,23 +23,15 @@ import time
 import traceback
 from typing import Dict, List, Optional, Tuple
 
-import numpy as np
-
-from repro.core.executor import ExecStats
-from repro.core.exprc import FusedStage, build_steps
+from repro.core.executor import Executor
 from repro.core.physical import PhysicalPlan, plan_from_wire
-from repro.core.relops import (AggMap, AggSpec, batch_kernel, batch_topk,
-                               concat_batches, count_shuffle,
-                               device_segment_reducer, merge_ordered,
-                               merge_topk, order_partition, probe_join)
 from repro.core.tcap import TCAPOp, TCAPProgram
-from repro.dist.exchange import (PeerAborted, SocketTransport, all_gather,
-                                 exchange_partitions, gather_to)
+from repro.dist.exchange import (PeerAborted, SocketTransport,
+                                 TransportExchange)
 from repro.dist.protocol import (DRIVER, HELLO, PROTO_VERSION, SETUP,
                                  WELCOME, ProtocolError, StatsFrame,
-                                 configure_socket, decode_agg_map,
-                                 encode_agg_map, read_frame, write_frame)
-from repro.obs.trace import NULL, SpanRecorder, current, op_name, using
+                                 configure_socket, read_frame, write_frame)
+from repro.obs.trace import NULL, SpanRecorder, using
 from repro.objectmodel.store import PagedSet, PagedStore
 from repro.objectmodel.vectorlist import VectorList
 
@@ -51,88 +39,20 @@ __all__ = ["WorkerRuntime", "worker_main", "connect_worker",
            "build_setup_shard", "run_remote_worker", "main"]
 
 
-def _batch_rows(batches: List[VectorList]) -> int:
-    """Total rows across a batch list (trace attribute only — called
-    solely when a recorder is enabled)."""
-    return sum(vl.num_rows or 0 for vl in batches)
-
-
-class WorkerRuntime:
-    """One worker: a rank, its shard store, and a transport to its peers."""
+class WorkerRuntime(Executor):
+    """One worker: a rank, its shard store, and a transport to its peers.
+    The program arrives optimized and planned, so it runs as shipped."""
 
     def __init__(self, rank: int, num_workers: int, transport,
                  shard: PagedStore, vector_rows: int = 8192,
                  expr_backend: str = "numpy"):
+        super().__init__(shard, num_workers, vector_rows, do_optimize=False,
+                         expr_backend=expr_backend)
         self.rank = rank
-        self.P = num_workers
         self.tr = transport
-        self.store = shard
-        self.vector_rows = vector_rows
-        self.expr_backend = expr_backend
-        self.stats = ExecStats()
+        self.exchange = TransportExchange(transport, num_workers)
 
-    # ------------------------------------------------------------ driver
-    def run(self, prog: TCAPProgram, plan: PhysicalPlan, rec=NULL) -> None:
-        """Execute the program; OUTPUT batches stream to the driver.
-        ``rec`` is this rank's span recorder (per-op spans; the exchange
-        patterns pick it up ambiently via ``obs.trace.using``).
-
-        The worker compiles its own stage plan from the shipped program
-        (:func:`~repro.core.exprc.build_steps`) — compilation is
-        deterministic and the kernel LRU is process-wide, so thread workers
-        share one jitted kernel per query shape and fork workers rebuild
-        identical ones (prefer ``worker_kind="thread"`` with
-        ``expr_backend="jax"``: XLA's runtime threads do not survive a
-        fork taken after jax initialized in the parent). Exchange ops
-        index the program by op position, so the fused steps are walked
-        with their op indices preserved."""
-        self.stats = ExecStats()
-        steps = build_steps(prog, self.expr_backend)
-        data: Dict[str, List[VectorList]] = {}
-        i = -1  # op index within prog (exchange tags key on it)
-        for step in steps:
-            if isinstance(step, FusedStage):
-                first, i = i + 1, i + len(step.ops)
-                name = op_name(first, i, [o.op for o in step.ops])
-                with rec.span(name, cat="op", idx=first) as sp:
-                    data[step.out] = [step(vl) for vl in data[step.in_list]]
-                if rec.enabled:
-                    sp.set(rows=_batch_rows(data[step.out]))
-                continue
-            op = step
-            i += 1
-            sb0 = self.stats.shuffle_bytes
-            with rec.span(op_name(i, i, [op.op]), cat="op",
-                          idx=i, op=op.op) as sp:
-                if op.op == "SCAN":
-                    data[op.out] = self._scan(op)
-                elif op.op in ("APPLY", "FILTER", "FLATTEN", "HASH"):
-                    kern = batch_kernel(op)
-                    data[op.out] = [kern(vl) for vl in data[op.in_list]]
-                elif op.op == "JOIN":
-                    algo = plan.join_algo.get(id(op), "hash_partition")
-                    data[op.out] = self._join(
-                        op, i, data[op.in_list], data[op.in_list2], algo,
-                        elide=plan.join_elide.get(id(op), ()))
-                elif op.op == "AGG":
-                    data[op.out] = self._aggregate(
-                        op, i, data[op.in_list],
-                        elide=id(op) in plan.agg_elide)
-                elif op.op == "TOPK":
-                    data[op.out] = self._topk(op, i, data[op.in_list])
-                elif op.op == "SORT":
-                    data[op.out] = self._order(op, i, data[op.in_list])
-                elif op.op == "OUTPUT":
-                    self._output(op, i, data[op.in_list])
-                else:
-                    raise ValueError(f"unknown op {op.op}")
-            if rec.enabled:
-                sp.set(rows=(self.stats.rows_output if op.op == "OUTPUT"
-                             else _batch_rows(data[op.out])),
-                       bytes=self.stats.shuffle_bytes - sb0)
-
-    # --------------------------------------------------------------- ops
-    def _scan(self, op: TCAPOp) -> List[VectorList]:
+    def _scan(self, op: TCAPOp) -> List[List[VectorList]]:
         s = self.store.get_set(op.info["set"])
         col = op.out_cols[0]
         batches: List[VectorList] = []
@@ -142,129 +62,12 @@ class WorkerRuntime:
             for j in range(0, len(page_records), self.vector_rows):
                 batches.append(
                     VectorList({col: page_records[j: j + self.vector_rows]}))
-        return batches
+        return [batches]
 
-    def _join(self, op: TCAPOp, i: int, left: List[VectorList],
-              right: List[VectorList], algo: str,
-              elide: Tuple[str, ...] = ()) -> List[VectorList]:
-        if algo == "broadcast":
-            self.stats.broadcast_joins += 1
-            srcs = all_gather(self.tr, self.P, f"{i}:build", right,
-                              self.stats)
-            rvl = concat_batches([vl for src in srcs for vl in src])
-            lvl = concat_batches(left)
-        else:
-            self.stats.hash_partition_joins += 1
-            # an elided side was proven already hash-partitioned on its
-            # join key (PL202): every row routes back to this rank, every
-            # peer's split toward us is empty — the exchange is the
-            # identity permutation. All ranks take the branch together
-            # (join_elide ships with the wire plan), so no rank blocks
-            # in recv.
-            if "L" in elide:
-                self.stats.exchanges_elided += 1
-                lvl = concat_batches(left)
-            else:
-                lvl = exchange_partitions(self.tr, self.P, f"{i}:L", left,
-                                          op.apply_cols[0], self.stats)
-            if "R" in elide:
-                self.stats.exchanges_elided += 1
-                rvl = concat_batches(right)
-            else:
-                rvl = exchange_partitions(self.tr, self.P, f"{i}:R", right,
-                                          op.apply_cols2[0], self.stats)
-        probed = probe_join(op, lvl, rvl)
-        if probed is None:
-            return []
-        res, n = probed
-        self.stats.rows_joined += n
-        return [res]
-
-    def _aggregate(self, op: TCAPOp, i: int, batches: List[VectorList],
-                   elide: bool = False) -> List[VectorList]:
-        spec = AggSpec.from_op(op)
-        kcols, acols = spec.key_cols(op), spec.acc_cols(op)
-        reducer = (device_segment_reducer(spec.combiners)
-                   if self.expr_backend == "jax" else None)
-        # one absorb over the shard's concatenated rows (shared with the
-        # local simulation — identical association order by construction)
-        m = AggMap(spec)
-        m.absorb_batches(batches, kcols, acols, reducer=reducer)
-        rec = current()
-        if elide:
-            # the planner proved this shard's rows are already stable_key_
-            # hash-partitioned on the key tuple: every key in `m` routes
-            # back to this rank, every peer's split toward us is empty —
-            # the exchange is the identity permutation. All ranks take this
-            # branch together (agg_elide ships with the wire plan), so no
-            # rank blocks in recv.
-            self.stats.exchanges_elided += 1
-            with rec.span("agg:emit", cat="kernel"):
-                emitted = m.emit()
-            return [emitted] if emitted is not None else []
-        with rec.span("agg:split", cat="kernel"):
-            split = m.split_by_key_hash(self.P)
-        tag = f"{i}:partials"
-        # packed multi-column partial maps ride the same page-block wire
-        # as batches (accumulators cross the wire, never finalized means)
-        for dst in range(self.P):
-            if dst == self.rank:
-                continue
-            block = encode_agg_map(split[dst])
-            if block is not None:
-                count_shuffle(self.stats, block.nbytes)
-            self.tr.send(dst, tag, block)
-        parts = []
-        for src in range(self.P):
-            if src == self.rank:
-                parts.append(split[self.rank])
-            else:
-                block = self.tr.recv(src, tag)
-                if block is not None:
-                    parts.append(decode_agg_map(block, spec))
-        with rec.span("agg:merge", cat="kernel"):
-            final = AggMap.merged(spec, parts)
-        with rec.span("agg:emit", cat="kernel"):
-            emitted = final.emit()
-        return [emitted] if emitted is not None else []
-
-    def _topk(self, op: TCAPOp, i: int,
-              batches: List[VectorList]) -> List[VectorList]:
-        best_s: List[np.ndarray] = []
-        best_p: List[np.ndarray] = []
-        for vl in batches:
-            s, pay = batch_topk(op, vl)
-            best_s.append(s)
-            best_p.append(pay)
-        local = ([VectorList({"score": np.concatenate(best_s),
-                              "payload": np.concatenate(best_p)})]
-                 if best_s else [])
-        gathered = gather_to(self.tr, self.P, f"{i}:topk", 0, local,
-                             self.stats)
-        if gathered is None:  # not the merge root
-            return []
-        cand_s = [np.asarray(vl["score"]) for src in gathered for vl in src]
-        cand_p = [np.asarray(vl["payload"]) for src in gathered for vl in src]
-        merged = merge_topk(op, cand_s, cand_p)
-        return [merged] if merged is not None else []
-
-    def _order(self, op: TCAPOp, i: int,
-               batches: List[VectorList]) -> List[VectorList]:
-        col = op.apply_cols[0]
-        sel = order_partition(op, batches)
-        local = [VectorList({col: sel})] if sel is not None else []
-        gathered = gather_to(self.tr, self.P, f"{i}:sort", 0, local,
-                             self.stats)
-        if gathered is None:  # not the merge root
-            return []
-        merged = merge_ordered(op, [np.asarray(vl[col]) for src in gathered
-                                    for vl in src])
-        return [merged] if merged is not None else []
-
-    def _output(self, op: TCAPOp, i: int, batches: List[VectorList]) -> None:
-        out = [vl.project(op.apply_cols) for vl in batches]
+    def _output(self, op: TCAPOp, i: int, parts) -> None:
+        out = [vl.project(op.apply_cols) for vl in parts[0]]
         self.stats.rows_output = sum(vl.num_rows or 0 for vl in out)
-        gather_to(self.tr, self.P, f"{i}:output", DRIVER, out, self.stats)
+        self.exchange.gather([out], f"{i}:output", self.stats, root=DRIVER)
 
 
 def worker_main(rank: int, num_workers: int, transport, shard: PagedStore,
@@ -278,14 +81,21 @@ def worker_main(rank: int, num_workers: int, transport, shard: PagedStore,
     worker injects its write-materializing subclass). Returns whether the
     query completed here — False when it aborted (a peer failed) or this
     worker errored, so process-worker entry points can exit nonzero for
-    supervisors."""
-    rt = (runtime_cls or WorkerRuntime)(rank, num_workers, transport,
-                                        shard, vector_rows, expr_backend)
+    supervisors.
+
+    The worker compiles its own stage plan from the shipped program —
+    compilation is deterministic and the kernel LRU is process-wide, so
+    thread workers share one jitted kernel per query shape and fork
+    workers rebuild identical ones (prefer ``worker_kind="thread"`` with
+    ``expr_backend="jax"``: XLA's runtime threads do not survive a fork
+    taken after jax initialized in the parent)."""
     rec = SpanRecorder(rank=rank) if trace else NULL
     try:
+        rt = (runtime_cls or WorkerRuntime)(rank, num_workers, transport,
+                                            shard, vector_rows, expr_backend)
         with using(rec):
             with rec.span("worker", cat="phase", rank=rank):
-                rt.run(prog, plan, rec)
+                rt.execute_program(prog, plan, trace=rec)
         transport.send(DRIVER, "done",
                        StatsFrame(rt.stats, list(rec.spans)))
         return True

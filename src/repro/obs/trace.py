@@ -61,9 +61,9 @@ __all__ = ["Span", "SpanRecorder", "NullRecorder", "NULL", "QueryTrace",
 
 def op_name(first: int, last: int, kinds) -> str:
     """The canonical span name for the op (or fused op run) covering
-    program indices ``first..last`` — one definition shared by the local
-    executor and the worker runtime, so the per-op span names of a plan
-    are identical across backends (a property the span-shape tests pin)."""
+    program indices ``first..last`` — the same on every backend, since
+    the local executor and the worker runtime share one op layer (the
+    span-shape tests pin it)."""
     label = "+".join(kinds)
     prefix = f"op{first}" if first == last else f"op{first}-{last}"
     return f"{prefix}:{label}"

@@ -7,7 +7,10 @@ carry :func:`~repro.dist.protocol.mux_tag`-namespaced tags
 (``"<qid>|<tag>"``); the demux loop routes each to its query's inbox, and
 each query runs in its own thread over a :class:`MuxTransport` facade
 that looks exactly like a :class:`~repro.dist.exchange.SocketTransport`
-to the unchanged :class:`~repro.dist.worker.WorkerRuntime`.
+to the rank's transport exchange. The query itself runs through the one
+op layer (:class:`~repro.core.executor.Executor`) as a
+:class:`ResidentWorkerRuntime`: the worker runtime with OUTPUT, its only
+override, able to materialize in place.
 
 Control frames from the service (bare tags, never mux-prefixed):
 
@@ -114,12 +117,12 @@ class ResidentWorkerRuntime(WorkerRuntime):
         self._retained = retained
         self._retained_lock = retained_lock
 
-    def _output(self, op, i, batches) -> None:
+    def _output(self, op, i, parts) -> None:
         if self._write is None:
-            return super()._output(op, i, batches)
+            return super()._output(op, i, parts)
         name, version = self._write["name"], self._write["version"]
         cols: Dict[str, list] = {c: [] for c in op.apply_cols}
-        for vl in batches:
+        for vl in parts[0]:
             for c in op.apply_cols:
                 cols[c].append(np.asarray(vl[c]))
         arrays = {c: (np.concatenate(v) if v else None)

@@ -10,9 +10,16 @@ What must hold, locally and on thread workers:
 * ``exchange.shuffle.bytes.total`` moves by exactly the query's
   ``stats.shuffle_bytes`` (join shuffles, broadcasts, AGG partials,
   gathers);
-* with tracing off nothing is recorded.
+* with tracing off nothing is recorded;
+* one fixed query (a hash or broadcast join, an AGG with partials and an
+  ORDER BY ... LIMIT) records exactly the span tree and moves exactly the
+  shuffle bytes in ``golden_op_layer.json``, locally and on two thread
+  workers: the op layer is shared, and only the exchange differs.
 """
 from __future__ import annotations
+
+import json
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -141,3 +148,23 @@ def test_tracing_off_records_nothing():
     assert sess.last_trace is None and NULL.spans == []
     for c in want:
         np.testing.assert_array_equal(got[c], want[c])
+
+
+GOLDEN = json.loads(
+    (Path(__file__).parent / "golden_op_layer.json").read_text())
+GOLDEN_RUNTIMES = {"local": {"num_partitions": 4},
+                   "thread": {"backend": "workers", "num_workers": 2,
+                              "worker_kind": "thread"}}
+GOLDEN_THRESHOLDS = {"hash_partition": 0, "broadcast": 2 << 30}
+
+
+@pytest.mark.parametrize("case", list(GOLDEN))
+def test_span_shape_and_shuffle_bytes_match_golden(case):
+    runtime, algo = case.split("-")
+    fact, dim = _tables(n_fact=20_000, n_dim=2000)
+    sess = Session(trace=True, broadcast_threshold_bytes=GOLDEN_THRESHOLDS[
+        algo], **GOLDEN_RUNTIMES[runtime])
+    _query(sess, fact, dim).collect()
+    assert sess.last_stats.shuffle_bytes == GOLDEN[case]["shuffle_bytes"]
+    assert ([list(s) for s in sess.last_trace.shape()]
+            == GOLDEN[case]["shape"])
